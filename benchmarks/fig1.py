@@ -165,7 +165,7 @@ def _collective_scan_cost(mesh, dim: int, iters: int, timed) -> float:
     """
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec
 
     axis = mesh.axis_names[0]
@@ -173,7 +173,7 @@ def _collective_scan_cost(mesh, dim: int, iters: int, timed) -> float:
 
     @jax.jit
     @partial(shard_map, mesh=mesh, in_specs=(spec,), out_specs=spec,
-             check_rep=False)
+             check_vma=False)
     def collective_scan(w):
         scale = 1.0 / mesh.devices.size
 

@@ -301,6 +301,9 @@ def main() -> None:
     sys.path.insert(0, ".")  # examples/ imports
     from benchmarks import (fig1, kernels_bench, multihost, roofline_table,
                             serve_bench, theory)
+    from repro._env import enable_compilation_cache
+
+    enable_compilation_cache()
 
     fig1_kw = (dict(iters=40, seeds=8, n_clients=8) if args.fast
                else dict(iters=100, seeds=8, n_clients=8))

@@ -15,6 +15,9 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# The suites run on the CPU backend, where Pallas kernels run in
+# interpret mode; chip_smoke.py is the on-chip check.
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 stage() {
     echo "=== stage: $1 ==="

@@ -1,10 +1,11 @@
 """Pre-jax-import environment helpers.
 
-This module must stay free of jax (and jax-importing repro) imports:
-its callers run *before* the first jax import, which is the only moment
-XLA client flags can still take effect. (``repro`` is a namespace
-package — no ``__init__.py`` — so ``from repro._env import ...`` pulls
-in nothing else.)
+This module must stay free of jax (and jax-importing repro) imports at
+module level: its callers run *before* the first jax import, which is
+the only moment XLA client flags can still take effect
+(:func:`enable_compilation_cache` imports jax when it is called).
+(``repro`` is a namespace package — no ``__init__.py`` — so
+``from repro._env import ...`` pulls in nothing else.)
 """
 
 from __future__ import annotations
@@ -12,6 +13,7 @@ from __future__ import annotations
 import os
 import sys
 import warnings
+from pathlib import Path
 
 
 def ensure_host_device_count(n: int = 8) -> bool:
@@ -92,3 +94,31 @@ def distributed_env() -> dict | None:
     local = os.environ.get(DIST_LOCAL_DEVICES)
     return {"coordinator": coord, "num_processes": nproc, "process_id": pid,
             "local_devices": int(local) if local is not None else None}
+
+
+#: Environment variable that places JAX's persistent compilation cache
+#: (JAX itself reads it when it is imported).
+COMPILATION_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+#: Where the cache goes when that variable is unset: a fixed path inside
+#: the checkout. The directory is part of the cache key, so a temporary,
+#: per-process or per-run name would never hit.
+DEFAULT_COMPILATION_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when set, and then no directory
+    is set in code. Otherwise the cache goes to
+    :data:`DEFAULT_COMPILATION_CACHE`. Entry points call this before
+    their first compile; it imports jax, so call it after
+    :func:`ensure_host_device_count`.
+    """
+    path = os.environ.get(COMPILATION_CACHE_ENV)
+    if path:
+        return path
+    import jax
+
+    path = str(DEFAULT_COMPILATION_CACHE)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

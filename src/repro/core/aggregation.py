@@ -334,7 +334,10 @@ def reduce_flat(g: jax.Array, weights: jax.Array, *,
         return agg_ops.masked_scaled_aggregate(
             g, weights.astype(jnp.float32), out_dtype=od, mask=mask)
     acc = jnp.promote_types(g.dtype, jnp.float32)
-    out = weights.astype(acc) @ _mask_rows(g, mask).astype(acc)
+    # HIGHEST: the TPU's default matmul precision rounds f32 operands to
+    # bf16, which would break the f32 contract above (and the kernel's).
+    out = jnp.matmul(weights.astype(acc), _mask_rows(g, mask).astype(acc),
+                     precision=jax.lax.Precision.HIGHEST)
     return out.astype(od)
 
 

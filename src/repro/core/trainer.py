@@ -425,7 +425,8 @@ class ClientSimulator:
         """
         scheduler, energy = self._components(scheduler, energy)
         faults = self._fault(faults)
-        if donate and spec is not None and jax.core.trace_state_clean():
+        if donate and spec is not None and not _any_tracer(
+                (carry, scheduler, energy, faults, p, active_mask)):
             carry, outs = _run_carry_donated(
                 carry, scheduler, energy, faults, p, active_mask,
                 sim=self, num_steps=int(num_steps), spec=spec)
@@ -440,6 +441,13 @@ class ClientSimulator:
         return SimHistory(loss=outs["loss"], participation=outs["participation"],
                           weight_sum=outs["weight_sum"],
                           finite=outs["finite"])
+
+
+def _any_tracer(tree) -> bool:
+    """True when any leaf of ``tree`` is a tracer — i.e. the caller is
+    under an enclosing trace (vmap/jit/scan), not at the top level."""
+    return any(isinstance(x, jax.core.Tracer)
+               for x in jax.tree_util.tree_leaves(tree))
 
 
 @functools.partial(jax.jit, static_argnames=("sim", "num_steps", "spec"),

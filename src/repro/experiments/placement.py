@@ -66,7 +66,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.core.energy import client_sharding
@@ -378,7 +378,7 @@ def _run_group_sharded(scheduler, energy, faults, active, p, params0, keys,
             return over_cells(sch, en, flt, act, pw, ks)
 
     fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+                   out_specs=out_specs, check_vma=False)
     out = fn(sch_leaves, en_leaves, flt_leaves, active, p, keys, params0)
     if replicate_out:
         # Multi-process dispatch: assemble fully-replicated outputs
@@ -429,7 +429,7 @@ def _run_cell_client_sharded(scheduler, energy, active, p, params0, key, *,
     fn = shard_map(local, mesh=mesh,
                    in_specs=(percell(scheduler), percell(energy), rows, rows,
                              replicated, replicated),
-                   out_specs=out_specs, check_rep=False)
+                   out_specs=out_specs, check_vma=False)
     out = fn(sch_leaves, en_leaves, active, p, key, params0)
     if replicate_out:
         # See _run_group_sharded: in-executable assembly for the

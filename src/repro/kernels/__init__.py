@@ -1,5 +1,5 @@
 """Pallas TPU kernels for the compute hot-spots (validated in interpret
-mode on CPU; Mosaic-compiled on TPU):
+mode on the CPU backend; Mosaic-compiled on TPU):
 
 * ``aggregate`` — masked/scaled client-gradient aggregation (the paper's
   server update, eq. 11/12) and the fused reduce-and-update step
@@ -9,17 +9,17 @@ mode on CPU; Mosaic-compiled on TPU):
 Each ships ``ops.py`` (jit'd wrapper) and ``ref.py`` (pure-jnp oracle).
 """
 
+import jax
 from jax.experimental.pallas import tpu as pltpu
 
-#: The installed jax's TPU compiler-params dataclass: jax < 0.5 exposes
-#: ``TPUCompilerParams``, newer releases renamed it ``CompilerParams``.
-#: One shim for every kernel package (``tests/test_kernels.py`` pins
-#: that this resolves to whichever symbol the installed jax exports).
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+#: The TPU compiler-params dataclass every kernel passes to
+#: ``pallas_call`` (``tests/test_kernels.py`` pins the binding).
+CompilerParams = pltpu.CompilerParams
 
 
-def tpu_compiler_params(**kwargs):
-    """Build the installed jax's TPU compiler-params object — the shared
-    seam for the ``CompilerParams`` / ``TPUCompilerParams`` rename."""
-    return CompilerParams(**kwargs)
+def interpret_default() -> bool:
+    """Interpret mode for the CPU backend only (tests and
+    ``JAX_PLATFORMS=cpu`` rehearsals). Every other backend gets the
+    Mosaic-compiled kernel, so a run that expected a chip and did not
+    get one fails instead of quietly running the interpreter."""
+    return jax.default_backend() == "cpu"

@@ -11,11 +11,17 @@ VMEM; the client axis (N ≤ a few thousand) rides the sublane dimension in
 full so each grid step is a single MXU matvec over an (N, bp) tile. The
 weight vector is tiny and replicated to every grid step.
 
-Grid: (P // bp,). VMEM per step: N·bp·itemsize + bp·4 — with N=1024,
-bp=2048, f32: 8 MB, comfortably inside VMEM; ops.py shrinks bp for larger
-N. FLOPs 2·N·P, bytes ≈ N·P·itemsize ⇒ arithmetic intensity ~2/itemsize:
-firmly memory-bound, so the win vs. a naive XLA reduce chain is avoiding
-the (N,P)→(P,) reduction materializing intermediates in HBM.
+Grid: (P // bp,). VMEM per step (:func:`vmem_bytes`): Pallas
+double-buffers every blocked operand, so the (N, bp) gradient tile counts
+twice, plus one f32 copy of it for the body's row select / upcast, plus
+the small (1, bp) and (1, N) rows. N=1024, bp=2048, f32 is thus ~24 MiB,
+past v5e's 16 MiB default scoped-VMEM limit: ops.py halves bp until the
+footprint fits :data:`VMEM_BUDGET`, and every call passes its footprint
+as ``vmem_limit_bytes`` so very wide client axes (where even bp=128
+exceeds the budget) still compile. FLOPs 2·N·P, bytes ≈ N·P·itemsize ⇒
+arithmetic intensity ~2/itemsize: firmly memory-bound, so the win vs. a
+naive XLA reduce chain is avoiding the (N,P)→(P,) reduction
+materializing intermediates in HBM.
 """
 
 from __future__ import annotations
@@ -25,6 +31,44 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import CompilerParams
+
+#: Scoped-VMEM footprint the block choice aims under: v5e's default
+#: scoped limit, a quarter of its 128 MiB VMEM.
+VMEM_BUDGET = 16 * 1024 * 1024
+#: Headroom over the accounted footprint for Mosaic's own scratch.
+_VMEM_SLACK = 4 * 1024 * 1024
+#: Physical VMEM of one v5e TensorCore: no limit may ask for more.
+VMEM_PHYSICAL = 128 * 1024 * 1024
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def vmem_bytes(n: int, bp: int, itemsize: int) -> int:
+    """Accounted VMEM of one grid step over an (n, bp) gradient tile.
+
+    Every blocked operand is double-buffered: the gradient tile (rows
+    padded to the sublane packing, 8 for f32 and 16 for bf16), the
+    (1, bp) parameter/output tiles and the (1, n) weight/mask rows (each
+    padded to 8 f32 sublanes). The kernel bodies also hold one f32 copy
+    of the tile (mask select, bf16 upcast).
+    """
+    tile = _round_up(n, 32 // itemsize) * bp * itemsize
+    rows = 8 * 4 * (2 * bp + 2 * _round_up(n, 128))
+    return 2 * (tile + rows) + _round_up(n, 8) * bp * 4
+
+
+def _compiler_params(n: int, bp: int, itemsize: int) -> CompilerParams:
+    limit = max(VMEM_BUDGET, vmem_bytes(n, bp, itemsize)) + _VMEM_SLACK
+    if limit > VMEM_PHYSICAL:
+        raise ValueError(
+            f"aggregation kernel at N={n}, bp={bp}, itemsize={itemsize} "
+            f"needs {limit} B of VMEM, more than the chip's "
+            f"{VMEM_PHYSICAL} B; shard the client axis (DESIGN.md §8)")
+    return CompilerParams(vmem_limit_bytes=limit)
 
 
 def _agg_kernel(w_ref, g_ref, o_ref):
@@ -98,6 +142,7 @@ def masked_scaled_aggregate_kernel(g, w, mask=None, *, block_p: int = 2048,
     out_shape = jax.ShapeDtypeStruct(
         (1, pp), jnp.dtype(out_dtype) if out_dtype is not None else g.dtype)
     w_op = w.reshape(1, n).astype(jnp.float32)
+    cparams = _compiler_params(n, bp, g.dtype.itemsize)
     vec_spec = pl.BlockSpec((1, n), lambda i: (0, 0))
     g_spec = pl.BlockSpec((n, bp), lambda i: (0, i))
     o_spec = pl.BlockSpec((1, bp), lambda i: (0, i))
@@ -108,6 +153,7 @@ def masked_scaled_aggregate_kernel(g, w, mask=None, *, block_p: int = 2048,
             in_specs=[vec_spec, g_spec],
             out_specs=o_spec,
             out_shape=out_shape,
+            compiler_params=cparams,
             interpret=interpret,
         )(w_op, g)
     else:
@@ -118,6 +164,7 @@ def masked_scaled_aggregate_kernel(g, w, mask=None, *, block_p: int = 2048,
             in_specs=[vec_spec, vec_spec, g_spec],
             out_specs=o_spec,
             out_shape=out_shape,
+            compiler_params=cparams,
             interpret=interpret,
         )(w_op, m_op, g)
     return out[0, :p]
@@ -165,6 +212,7 @@ def masked_scaled_aggregate_update_kernel(g, w, eta, params=None, mask=None,
     m_op = (jnp.ones((1, n), jnp.float32) if mask is None
             else mask.reshape(1, n).astype(jnp.float32))
     eta_op = jnp.asarray(eta, jnp.float32).reshape(1, 1)
+    cparams = _compiler_params(n, bp, g.dtype.itemsize)
     scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
     vec_spec = pl.BlockSpec((1, n), lambda i: (0, 0))
     g_spec = pl.BlockSpec((n, bp), lambda i: (0, i))
@@ -176,6 +224,7 @@ def masked_scaled_aggregate_update_kernel(g, w, eta, params=None, mask=None,
             in_specs=[scalar_spec, vec_spec, vec_spec, g_spec],
             out_specs=tile_spec,
             out_shape=out_shape,
+            compiler_params=cparams,
             interpret=interpret,
         )(eta_op, w_op, m_op, g)
     else:
@@ -188,6 +237,7 @@ def masked_scaled_aggregate_update_kernel(g, w, eta, params=None, mask=None,
             in_specs=[scalar_spec, vec_spec, vec_spec, g_spec, tile_spec],
             out_specs=tile_spec,
             out_shape=out_shape,
+            compiler_params=cparams,
             interpret=interpret,
         )(eta_op, w_op, m_op, g, p_op)
     return out[0, :p]

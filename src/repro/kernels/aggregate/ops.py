@@ -1,9 +1,13 @@
 """Jit'd public wrapper for the aggregation kernel.
 
-On CPU (this container) the kernel runs in interpret mode — the kernel
-body executes in Python per grid step, validating the exact TPU program.
-On TPU it compiles to Mosaic. VMEM budgeting: shrink the parameter tile
-so the (N, bp) block stays ≤ ~8 MB.
+On the CPU backend (tests, ``JAX_PLATFORMS=cpu`` rehearsals) the kernel
+runs in interpret mode — the kernel body executes per grid step,
+validating the exact TPU program. Any other backend compiles it with
+Mosaic. VMEM budgeting: the parameter tile bp is halved until one grid
+step's accounted footprint (``aggregate.vmem_bytes``: the double-buffered
+(N, bp) tile, one f32 copy of it, and the small rows) fits
+``aggregate.VMEM_BUDGET``; the kernel passes that footprint on as its
+``vmem_limit_bytes``.
 """
 
 from __future__ import annotations
@@ -13,20 +17,17 @@ import jax
 from repro.core.aggregation import compose_masks  # noqa: F401  (re-export:
 # the mask operand of every kernel below accepts a composed product of
 # active/delivery masks — canonical impl lives with the mask machinery)
+from repro.kernels import interpret_default
 from repro.kernels.aggregate.aggregate import (
+    VMEM_BUDGET,
     masked_scaled_aggregate_kernel,
     masked_scaled_aggregate_update_kernel,
+    vmem_bytes,
 )
-
-_VMEM_BUDGET = 8 * 1024 * 1024
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _fit_block(n: int, itemsize: int, block_p: int) -> int:
-    while block_p > 128 and n * block_p * itemsize > _VMEM_BUDGET:
+    while block_p > 128 and vmem_bytes(n, block_p, itemsize) > VMEM_BUDGET:
         block_p //= 2
     return block_p
 
@@ -42,7 +43,7 @@ def masked_scaled_aggregate(g, w, block_p: int = 2048, out_dtype=None,
     """
     block_p = _fit_block(g.shape[0], g.dtype.itemsize, block_p)
     return masked_scaled_aggregate_kernel(
-        g, w, mask, block_p=block_p, interpret=_interpret(),
+        g, w, mask, block_p=block_p, interpret=interpret_default(),
         out_dtype=out_dtype)
 
 
@@ -61,8 +62,8 @@ def masked_scaled_aggregate_update(g, w, eta, params=None, mask=None, *,
     """
     block_p = _fit_block(g.shape[0], g.dtype.itemsize, block_p)
     return masked_scaled_aggregate_update_kernel(
-        g, w, eta, params, mask, block_p=block_p, interpret=_interpret(),
-        out_dtype=out_dtype)
+        g, w, eta, params, mask, block_p=block_p,
+        interpret=interpret_default(), out_dtype=out_dtype)
 
 
 def masked_scaled_aggregate_sharded(g, w, *, axis_name: str,

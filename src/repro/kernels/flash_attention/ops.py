@@ -1,24 +1,20 @@
 """Jit'd public wrapper for flash attention.
 
 Accepts the model's (B, S, H, Dh) layout, transposes to the kernel's
-(B, H, S, Dh), selects interpret mode off-TPU, and falls back to the ref
-for shapes the kernel can't tile (tiny/unaligned smoke shapes).
+(B, H, S, Dh), selects interpret mode on the CPU backend, and falls back
+to the ref for shapes the kernel can't tile (tiny/unaligned smoke
+shapes).
 """
 
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import interpret_default
 from repro.kernels.flash_attention.flash_attention import (
     DEFAULT_BLOCK_K,
     DEFAULT_BLOCK_Q,
     flash_attention_kernel,
 )
 from repro.kernels.flash_attention.ref import flash_attention_ref
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, *, causal=True, window=0,
@@ -34,5 +30,5 @@ def flash_attention(q, k, v, *, causal=True, window=0,
     else:
         out = flash_attention_kernel(qh, kh, vh, causal=causal, window=window,
                                      block_q=bq, block_k=bk,
-                                     interpret=_interpret())
+                                     interpret=interpret_default())
     return out.swapaxes(1, 2)

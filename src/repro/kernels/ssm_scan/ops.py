@@ -6,14 +6,10 @@ sequence is padded to the chunk size with a=1, k=v=0 (identity steps).
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.ssm_scan.ssm_scan import gla_scan_kernel
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def gla_scan(a, k, v, q, chunk: int = 64):
@@ -29,6 +25,6 @@ def gla_scan(a, k, v, q, chunk: int = 64):
         vf = jnp.pad(vf, ((0, 0), (0, pad), (0, 0)))
         qf = jnp.pad(qf, ((0, 0), (0, pad), (0, 0)))
     y = gla_scan_kernel(af, kf, vf, qf, chunk=min(chunk, af.shape[1]),
-                        interpret=_interpret())
+                        interpret=interpret_default())
     y = y[:, :s]
     return y.reshape(b, h, s, dv).swapaxes(1, 2)

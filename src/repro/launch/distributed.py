@@ -286,7 +286,10 @@ def launch_simulated(num_processes: int = 2, local_devices: int = 4, *,
     fresh localhost port — the simulated multi-host CI story
     (DESIGN.md §13). The parent's own XLA device-count flag is stripped
     from the children's environment so the per-worker pin always wins
-    (the parent test/bench session typically forced 8 devices already).
+    (the parent test/bench session typically forced 8 devices already),
+    and ``JAX_PLATFORMS=cpu`` is set explicitly: this is a CPU-only
+    harness, and on a machine with a chip a worker must not contend
+    with the parent (or its siblings) for it.
     Returns the completed processes in process-id order; raises if any
     worker exits non-zero (its stderr in the message) or hangs past
     ``timeout``.
@@ -299,6 +302,7 @@ def launch_simulated(num_processes: int = 2, local_devices: int = 4, *,
         env[DIST_NUM_PROCESSES] = str(num_processes)
         env[DIST_PROCESS_ID] = str(pid)
         env[DIST_LOCAL_DEVICES] = str(local_devices)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = _DEVICE_COUNT_FLAG.sub(
             "", env.get("XLA_FLAGS", "")).strip()
         env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")

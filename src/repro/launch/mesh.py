@@ -16,6 +16,7 @@ before any jax import so 512 placeholder CPU devices exist.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # Grid sharding wants a flat 1-D mesh over the (scenario × seed) cell
 # axis regardless of how training meshes are shaped; within-cell client
@@ -31,12 +32,19 @@ from repro.experiments.placement import (  # noqa: F401
 )
 
 
+def _auto_mesh(shape, axes):
+    # Model code constrains activations with ``with_sharding_constraint``
+    # under ``with mesh:`` (models/common.maybe_shard), which only accepts
+    # Auto axes; ``jax.make_mesh`` defaults to Explicit ones.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke paths (tests, examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))

@@ -23,7 +23,8 @@ one compiled trace:
         --checkpoint-root /tmp/serve-ck --recover
 
 Prints one summary line per request (cells, quarantined cells, latency)
-plus the batch/cache counters that show the single-trace collapse.
+plus the batch/cache counters that show the single-trace collapse, and
+exits non-zero when any response carries an error.
 Replaces the seed-era LM decode driver; `examples/serve_batch.py` is
 the scripted client-side walkthrough.
 """
@@ -36,6 +37,7 @@ import json
 import jax
 import jax.numpy as jnp
 
+from repro._env import enable_compilation_cache
 from repro.core.convergence import make_quadratic
 from repro.experiments import ExecutionConfig, Study
 from repro.optim import sgd
@@ -145,4 +147,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
-    main()
+    enable_compilation_cache()
+    failed = [r for r in main() if r.error is not None]
+    raise SystemExit(1 if failed else 0)

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro._env import enable_compilation_cache
 from repro.checkpoint import CheckpointManager, latest_step
 from repro.configs import get_config
 from repro.core.energy import arrival_family_names
@@ -187,4 +188,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

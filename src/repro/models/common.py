@@ -14,10 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:  # non-deprecated home of the mesh context (jax ≥ 0.4.x internals)
-    from jax._src.mesh import thread_resources as _thread_resources
-except ImportError:  # pragma: no cover - older jax
-    from jax.interpreters.pxla import thread_resources as _thread_resources
+from jax._src.mesh import thread_resources as _thread_resources
 
 
 def current_mesh():

@@ -123,7 +123,7 @@ def _apply_moe_shardmap(params, x, *, n_experts, top_k, act,
     """
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -152,7 +152,7 @@ def _apply_moe_shardmap(params, x, *, n_experts, top_k, act,
                 P("model", None, None), P(dp_axes, None, None))
     out_specs = (P(dp_axes, None, None), P())
     fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+                   check_vma=False)
     return fn(params["router"]["w"], params["w_gate"], params["w_up"],
               params["w_down"], x)
 

@@ -18,6 +18,7 @@ Locks the tentpole contracts of the fused Pallas server step:
 import warnings
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -176,7 +177,7 @@ def test_cross_shard_sum_bf16_wire_f32_accumulation():
     """The bf16 wire quantizes each shard's partial ONCE and accumulates
     the gathered partials in f32 — bitwise equal to the explicit
     quantize-then-f32-sum, not to a bf16-accumulated psum."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_client_mesh()
@@ -187,7 +188,7 @@ def test_cross_shard_sum_bf16_wire_f32_accumulation():
     fn = shard_map(
         lambda x: _cross_shard_sum(x[0], "clients", jnp.bfloat16)[None],
         mesh=mesh, in_specs=P("clients"), out_specs=P("clients"),
-        check_rep=False)
+        check_vma=False)
     out = np.asarray(fn(partials)[0])
     expected = np.sum(np.asarray(partials.astype(jnp.bfloat16)
                                  .astype(jnp.float32)), axis=0)
@@ -206,10 +207,10 @@ def _count_pallas_calls(jaxpr) -> int:
         for v in eqn.params.values():
             for sub in jax.tree_util.tree_leaves(
                     v, is_leaf=lambda x: isinstance(
-                        x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                        x, (jex_core.Jaxpr, jex_core.ClosedJaxpr))):
+                if isinstance(sub, jex_core.ClosedJaxpr):
                     n += _count_pallas_calls(sub.jaxpr)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jex_core.Jaxpr):
                     n += _count_pallas_calls(sub)
     return n
 

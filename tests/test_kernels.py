@@ -160,32 +160,44 @@ def test_gla_scan_sweep(b, s, h, dk, dv, chunk):
     np.testing.assert_allclose(y, ref, rtol=2e-4, atol=2e-4)
 
 
-# ------------------------------------------------- compiler-params shim
+# ------------------------------------------------- compiler-params binding
 
 def test_compiler_params_shim_resolves_installed_symbol():
-    """``repro.kernels.CompilerParams`` must be the one dataclass the
-    installed jax exports (``CompilerParams`` on new releases,
-    ``TPUCompilerParams`` before the rename) — every kernel module
-    imports this single shim instead of re-probing pltpu."""
+    """``repro.kernels.CompilerParams`` is the installed jax's one TPU
+    compiler-params dataclass, and the aggregation kernels' VMEM limit
+    rides it."""
     from jax.experimental.pallas import tpu as pltpu
 
     import repro.kernels as rk
+    from repro.kernels.aggregate import aggregate as agg
 
-    expected = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    assert rk.CompilerParams is expected
-    params = rk.tpu_compiler_params(dimension_semantics=("arbitrary",))
+    assert rk.CompilerParams is pltpu.CompilerParams
+    params = agg._compiler_params(1024, 1024, 4)
     assert isinstance(params, rk.CompilerParams)
+    assert params.vmem_limit_bytes >= agg.vmem_bytes(1024, 1024, 4)
 
 
 def test_kernel_modules_use_shared_shim():
     """No kernel module keeps a private getattr-probe: they all bind the
-    package-level shim object."""
+    package-level object."""
     import importlib
 
     import repro.kernels as rk
 
     fa = importlib.import_module("repro.kernels.flash_attention.flash_attention")
     ss = importlib.import_module("repro.kernels.ssm_scan.ssm_scan")
+    ag = importlib.import_module("repro.kernels.aggregate.aggregate")
     assert fa._CompilerParams is rk.CompilerParams
     assert ss._CompilerParams is rk.CompilerParams
+    assert ag.CompilerParams is rk.CompilerParams
+
+
+def test_aggregate_vmem_limit_never_exceeds_physical_vmem():
+    """A client axis too wide for one core's VMEM even at bp=128 is
+    refused by name, not handed to Mosaic as an impossible limit."""
+    from repro.kernels.aggregate import aggregate as agg
+
+    ok = agg._compiler_params(10240, 128, 4)
+    assert agg.VMEM_BUDGET < ok.vmem_limit_bytes <= agg.VMEM_PHYSICAL
+    with pytest.raises(ValueError, match=r"N=100000, bp=128"):
+        agg._compiler_params(100_000, 128, 4)

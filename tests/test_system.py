@@ -1,10 +1,12 @@
 """End-to-end system tests: drivers, simulator, aggregation kernel path."""
 
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import ClientSimulator, make_quadratic, make_scheduler
 from repro.core.energy import DeterministicArrivals
@@ -47,6 +49,30 @@ def test_serve_driver_end_to_end(tmp_path):
     # -> exactly two compiles for the whole batch.
     assert responses[0].batch["requests"] == 5
     assert responses[0].cache["compiles"] == 2
+
+
+@pytest.mark.parametrize("fail", [False, True], ids=["served", "errored"])
+def test_serve_cli_exit_code_reflects_errored_responses(monkeypatch, capsys,
+                                                        tmp_path, fail):
+    """``python -m repro.launch.serve`` exits non-zero when any response
+    carries an error, instead of printing ERROR lines and exiting 0."""
+    import runpy
+
+    from repro.experiments import engine
+
+    if fail:
+        def execute_cells(*args, **kwargs):
+            raise RuntimeError("dispatch failed")
+
+        monkeypatch.setattr(engine, "execute_cells", execute_cells)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    monkeypatch.setattr(sys, "argv", ["serve", "--demo", "--demo-requests",
+                                      "2", "--demo-steps", "5"])
+    with pytest.raises(SystemExit) as exit_:
+        runpy.run_module("repro.launch.serve", run_name="__main__")
+    out = capsys.readouterr().out
+    assert exit_.value.code == (1 if fail else 0)
+    assert ("ERROR RuntimeError: dispatch failed" in out) == fail
 
 
 def test_simulator_kernel_aggregation_matches_jnp():
