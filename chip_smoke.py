@@ -206,8 +206,9 @@ def phase_train(ctx, params0) -> dict:
 
 
 def check_train(facts: dict) -> None:
-    check(facts["kernel_vs_jnp"]["ok"], "kernel and jnp runs differ beyond "
-          f"rtol={RTOL} atol={ATOL}")
+    diff = facts["kernel_vs_jnp"]["max_abs_diff"]
+    check(facts["kernel_vs_jnp"]["ok"], f"kernel and jnp runs differ by up "
+          f"to {diff} beyond rtol={RTOL} atol={ATOL}")
     check(facts["tpu_custom_call"],
           "kernel run lowered without a tpu_custom_call")
 
@@ -298,9 +299,10 @@ def check_sharded(facts: dict) -> None:
           f"device peaks {facts['peak_bytes']} below one gradient shard "
           f"({facts['shard_bytes']} B)")
     for reduction, result in facts["vs_vmap"].items():
-        check(result["ok"], f"{reduction} vs vmap: " + (
-            "not bitwise" if result["bitwise"] else
-            f"beyond rtol={RTOL} atol={ATOL}"))
+        check(result["ok"], f"{reduction} vs vmap differ by up to "
+              f"{result['max_abs_diff']}: " + (
+                  "not bitwise" if result["bitwise"] else
+                  f"beyond rtol={RTOL} atol={ATOL}"))
 
 
 def main(argv=None) -> int:

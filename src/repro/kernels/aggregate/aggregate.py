@@ -41,6 +41,11 @@ VMEM_BUDGET = 16 * 1024 * 1024
 _VMEM_SLACK = 4 * 1024 * 1024
 #: Physical VMEM of one v5e TensorCore: no limit may ask for more.
 VMEM_PHYSICAL = 128 * 1024 * 1024
+#: Contraction precision of every kernel body. Mosaic's default rounds
+#: f32 operands to bf16 on the MXU, as XLA's does; HIGHEST keeps the f32
+#: contract (and matches ``reduce_flat``'s jnp matvec, which asks for the
+#: same). The matvec stays memory-bound at the extra MXU passes.
+_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _round_up(x: int, m: int) -> int:
@@ -74,7 +79,7 @@ def _compiler_params(n: int, bp: int, itemsize: int) -> CompilerParams:
 def _agg_kernel(w_ref, g_ref, o_ref):
     # w: (1, N) f32; g: (N, bp); o: (1, bp)
     g = g_ref[...].astype(jnp.float32)
-    o_ref[...] = jnp.dot(w_ref[...], g,
+    o_ref[...] = jnp.dot(w_ref[...], g, precision=_PRECISION,
                          preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
@@ -85,7 +90,7 @@ def _agg_kernel_masked(w_ref, m_ref, g_ref, o_ref):
     # when its gradient row is inf/NaN garbage (0·inf would be NaN).
     g = g_ref[...].astype(jnp.float32)
     g = jnp.where(m_ref[...].T > 0, g, 0.0)
-    o_ref[...] = jnp.dot(w_ref[...], g,
+    o_ref[...] = jnp.dot(w_ref[...], g, precision=_PRECISION,
                          preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
@@ -99,7 +104,8 @@ def _agg_update_kernel(eta_ref, w_ref, m_ref, g_ref, p_ref, o_ref):
     # back only on the way out.
     g = g_ref[...].astype(jnp.float32)
     g = jnp.where(m_ref[...].T > 0, g, 0.0)
-    acc = jnp.dot(w_ref[...], g, preferred_element_type=jnp.float32)
+    acc = jnp.dot(w_ref[...], g, precision=_PRECISION,
+                  preferred_element_type=jnp.float32)
     o_ref[...] = (p_ref[...].astype(jnp.float32)
                   - eta_ref[0, 0] * acc).astype(o_ref.dtype)
 
@@ -112,7 +118,8 @@ def _agg_delta_kernel(eta_ref, w_ref, m_ref, g_ref, o_ref):
     # deltas equals the delta of the global reduction.
     g = g_ref[...].astype(jnp.float32)
     g = jnp.where(m_ref[...].T > 0, g, 0.0)
-    acc = jnp.dot(w_ref[...], g, preferred_element_type=jnp.float32)
+    acc = jnp.dot(w_ref[...], g, precision=_PRECISION,
+                  preferred_element_type=jnp.float32)
     o_ref[...] = (-eta_ref[0, 0] * acc).astype(o_ref.dtype)
 
 

@@ -201,3 +201,33 @@ def test_aggregate_vmem_limit_never_exceeds_physical_vmem():
     assert agg.VMEM_BUDGET < ok.vmem_limit_bytes <= agg.VMEM_PHYSICAL
     with pytest.raises(ValueError, match=r"N=100000, bp=128"):
         agg._compiler_params(100_000, 128, 4)
+
+
+@pytest.mark.parametrize("variant", ["plain", "masked", "update", "delta"])
+def test_aggregate_kernel_bodies_contract_at_f32(variant):
+    """Every aggregation kernel body asks for ``Precision.HIGHEST``: Mosaic's
+    default rounds f32 operands to bf16 on the MXU, which breaks the f32
+    contract the jnp matvec keeps (DESIGN.md §9)."""
+    from repro.kernels.aggregate.aggregate import (
+        masked_scaled_aggregate_update_kernel,
+    )
+
+    g, w = jnp.ones((8, 256)), jnp.ones((8,))
+    fn = {"plain": lambda: masked_scaled_aggregate_kernel(g, w),
+          "masked": lambda: masked_scaled_aggregate_kernel(g, w, w),
+          "update": lambda: masked_scaled_aggregate_update_kernel(
+              g, w, 0.1, jnp.ones((256,))),
+          "delta": lambda: masked_scaled_aggregate_update_kernel(
+              g, w, 0.1)}[variant]
+
+    def dots(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from dots(sub)
+
+    found = list(dots(jax.make_jaxpr(fn)().jaxpr))
+    assert found
+    assert all(eqn.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2
+               for eqn in found)
