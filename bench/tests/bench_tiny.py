@@ -1,0 +1,71 @@
+"""Helpers of the benchmark's CPU tests: paths, and a run of the harness
+at a tiny size with the look for a chip skipped."""
+
+from __future__ import annotations
+
+import contextlib
+import io
+import json
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+ROOT = BENCH.parent
+for _p in (BENCH, ROOT / "src", ROOT):
+    if str(_p) not in sys.path:
+        sys.path.insert(0, str(_p))
+
+
+def shrink(c: dict) -> dict:
+    """A loaded cell (``harness.main.load_cell``) cut to a CPU test's size:
+    8x8 images, 12 clients (data weights that bfloat16 cannot hold
+    exactly, as at full size), batches of 4, a few rounds; widths as
+    given. The learning rate is raised so that one round moves the small
+    model's held-out loss about as far as at full size."""
+    cfg = c["cfg"]
+    cfg["model"]["image_hw"] = 8
+    cfg["n_clients"] = 12
+    cfg["batch_size"] = 4
+    cfg["lr"] = 0.5
+    cfg["data"].update(n_train=192, n_eval=40, n_loss=32)
+    c["traffic"].update(num_steps=4, eval_every=4, seeds_per_run=2,
+                        check_answers=4)
+    return c
+
+
+def run_tiny(monkeypatch, workload: str, seed: int, seconds: float = 2.0,
+             trace: int = 0, edit=lambda c: c) -> dict:
+    """Run ``bench/run.py``'s main at a tiny size on the CPU and return
+    its result line; the chip check is skipped and JAX's configuration
+    (the compile cache) left as it is. ``edit`` may change the shrunk
+    cell further."""
+    import jax
+
+    from harness import device, main
+
+    real = main.load_cell
+    monkeypatch.setattr(main, "load_cell", lambda w: shrink(edit(real(w))))
+    monkeypatch.setattr(device, "require_tpu", lambda chips: {
+        "platform": "cpu", "kind": "cpu", "count": 1})
+    peaks = json.loads(device.PEAKS.read_text())["devices"]["TPU v5 lite"]
+    monkeypatch.setattr(device, "peaks", lambda kind: peaks)
+    monkeypatch.setattr(device, "memory_peak_bytes", lambda devs: 0)
+    out = io.StringIO()
+    with monkeypatch.context() as m, contextlib.redirect_stdout(out):
+        m.setattr(jax.config, "update", lambda *a: None)
+        rc = main.main(["--workload", workload, "--seed", str(seed),
+                        "--seconds", str(seconds), "--trace", str(trace)])
+    assert rc == 0
+    return json.loads(out.getvalue().strip().splitlines()[-1])
+
+
+def with_traffic(name: str):
+    """An ``edit`` for :func:`run_tiny` that runs the cell under the
+    traffic file ``bench/traffic/<name>.json``."""
+
+    def edit(c):
+        c["traffic"] = json.loads((BENCH / "traffic" / f"{name}.json")
+                                  .read_text())
+        return c
+
+    return edit
