@@ -220,26 +220,31 @@ class ClientSimulator:
                     "the fault component")
         p = self.p if p is None else p
         key, k_arr, k_sched, k_grad = jax.random.split(carry.key, 4)
-        energy_state, arr = energy.arrivals(carry.energy_state, carry.t, k_arr)
-        sched_state, dec = scheduler.step(carry.sched_state, carry.t, k_sched,
-                                          arr, active=active_mask)
-        weights = aggregation.client_weights(p, dec)
-        if active_mask is not None:
-            # Defensive exactness: zero weight for rows that don't exist
-            # even if a custom scheduler leaked probability mass to them
-            # (×1 on active rows — bit-exact).
-            weights = weights * active_mask
+        # The step's phases are named in the ops' metadata (sim.schedule,
+        # sim.grads, sim.update, sim.eval); the scopes change no op.
+        with jax.named_scope("sim.schedule"):
+            energy_state, arr = energy.arrivals(carry.energy_state, carry.t,
+                                                k_arr)
+            sched_state, dec = scheduler.step(carry.sched_state, carry.t,
+                                              k_sched, arr, active=active_mask)
+            weights = aggregation.client_weights(p, dec)
+            if active_mask is not None:
+                # Defensive exactness: zero weight for rows that don't
+                # exist even if a custom scheduler leaked probability
+                # mass to them (×1 on active rows — bit-exact).
+                weights = weights * active_mask
         wsum = None
         agg = params = opt_state = None
         fault_state = carry.fault_state
         row_mask = active_mask
         fusable = getattr(self.optimizer, "kind", "") == "sgd"
         if spec is not None:
-            params_tree = aggregation.unravel_pytree(carry.params, spec)
-            # The ravel boundary lives inside the wrapper: the scan body
-            # sees one flat (N, P) — or, sharded, (n_local, P) — buffer
-            # and carries no per-leaf concat.
-            g = self._flat_grads(spec)(params_tree, k_grad, carry.t)
+            with jax.named_scope("sim.grads"):
+                params_tree = aggregation.unravel_pytree(carry.params, spec)
+                # The ravel boundary lives inside the wrapper: the scan
+                # body sees one flat (N, P) — or, sharded, (n_local, P) —
+                # buffer and carries no per-leaf concat.
+                g = self._flat_grads(spec)(params_tree, k_grad, carry.t)
             if faults is not None:
                 # Delivery faults transform the flat rows and/or return a
                 # keep mask; keep composes into the active-row select so
@@ -254,63 +259,75 @@ class ClientSimulator:
                 if keep is not None:
                     weights = weights * keep
                     row_mask = aggregation.compose_masks(active_mask, keep)
-            if shard is not None:
-                mode, wire = aggregation.parse_reduction(shard.reduction)
-                if mode == "fused":
-                    if not fusable:
-                        raise ValueError(
-                            "reduction 'fused' bundles the SGD parameter "
-                            "update into the reduction kernel and needs a "
-                            "plain sgd() optimizer (kind='sgd'); use "
-                            "'psum' for stateful/clipped optimizers")
-                    params, opt_state, wsum = aggregation.fused_flat_sgd_update(
+            with jax.named_scope("sim.update"):
+                if shard is not None:
+                    mode, wire = aggregation.parse_reduction(shard.reduction)
+                    if mode == "fused":
+                        if not fusable:
+                            raise ValueError(
+                                "reduction 'fused' bundles the SGD "
+                                "parameter update into the reduction "
+                                "kernel and needs a plain sgd() optimizer "
+                                "(kind='sgd'); use 'psum' for "
+                                "stateful/clipped optimizers")
+                        params, opt_state, wsum = \
+                            aggregation.fused_flat_sgd_update(
+                                g, weights, carry.params, carry.opt_state,
+                                self.optimizer, mask=row_mask,
+                                use_kernel=self.use_kernel, shard=shard,
+                                wire_dtype=wire)
+                    else:
+                        agg, wsum = aggregation.reduce_flat_client_sharded(
+                            g, weights, axis_name=shard.axis_name,
+                            reduction=shard.reduction,
+                            use_kernel=self.use_kernel, mask=row_mask)
+                elif self.use_kernel and fusable:
+                    # Unsharded fused fast path: identical f32 op sequence
+                    # to reduce → −η·agg → add, collapsed into one Pallas
+                    # launch.
+                    params, opt_state, _ = aggregation.fused_flat_sgd_update(
                         g, weights, carry.params, carry.opt_state,
-                        self.optimizer, mask=row_mask,
-                        use_kernel=self.use_kernel, shard=shard,
-                        wire_dtype=wire)
+                        self.optimizer, mask=row_mask, use_kernel=True)
                 else:
-                    agg, wsum = aggregation.reduce_flat_client_sharded(
-                        g, weights, axis_name=shard.axis_name,
-                        reduction=shard.reduction,
-                        use_kernel=self.use_kernel, mask=row_mask)
-            elif self.use_kernel and fusable:
-                # Unsharded fused fast path: identical f32 op sequence to
-                # reduce → −η·agg → add, collapsed into one Pallas launch.
-                params, opt_state, _ = aggregation.fused_flat_sgd_update(
-                    g, weights, carry.params, carry.opt_state,
-                    self.optimizer, mask=row_mask, use_kernel=True)
-            else:
-                agg = aggregation.reduce_flat(g, weights,
-                                              use_kernel=self.use_kernel,
-                                              mask=row_mask)
+                    agg = aggregation.reduce_flat(g, weights,
+                                                  use_kernel=self.use_kernel,
+                                                  mask=row_mask)
         elif self.flat is False:
             # Full legacy semantics: per-leaf reductions (and per-leaf
             # kernel launches), leaf dtypes untouched — the escape hatch
             # and the reference the flat paths are tested against.
-            stacked = self.grads_fn(carry.params, k_grad, carry.t)
-            agg = (aggregation.aggregate_client_grads_kernel_per_leaf(
-                       stacked, weights, active_mask) if self.use_kernel
-                   else aggregation.aggregate_client_grads(stacked, weights,
-                                                           active_mask))
+            with jax.named_scope("sim.grads"):
+                stacked = self.grads_fn(carry.params, k_grad, carry.t)
+            with jax.named_scope("sim.update"):
+                agg = (aggregation.aggregate_client_grads_kernel_per_leaf(
+                           stacked, weights, active_mask) if self.use_kernel
+                       else aggregation.aggregate_client_grads(
+                           stacked, weights, active_mask))
         else:
-            stacked = self.grads_fn(carry.params, k_grad, carry.t)
-            agg = aggregation.aggregate_client_grads_flat(
-                stacked, weights, use_kernel=self.use_kernel,
-                mask=active_mask)
-        if params is None:
-            updates, opt_state = self.optimizer.update(
-                agg, carry.opt_state, carry.params)
-            params = apply_updates(carry.params, updates)
-        loss_params = (aggregation.unravel_pytree(params, spec)
-                       if spec is not None else params)
-        loss = (self.loss_fn(loss_params) if self.loss_fn is not None
-                else jnp.zeros((), jnp.float32))
-        if spec is not None:
-            finite = jnp.all(jnp.isfinite(params))
-        else:
-            finite = jnp.array(True)
-            for leaf in jax.tree_util.tree_leaves(params):
-                finite = jnp.logical_and(finite, jnp.all(jnp.isfinite(leaf)))
+            with jax.named_scope("sim.grads"):
+                stacked = self.grads_fn(carry.params, k_grad, carry.t)
+            with jax.named_scope("sim.update"):
+                agg = aggregation.aggregate_client_grads_flat(
+                    stacked, weights, use_kernel=self.use_kernel,
+                    mask=active_mask)
+        with jax.named_scope("sim.update"):
+            if params is None:
+                updates, opt_state = self.optimizer.update(
+                    agg, carry.opt_state, carry.params)
+                params = apply_updates(carry.params, updates)
+        with jax.named_scope("sim.eval"):
+            loss_params = (aggregation.unravel_pytree(params, spec)
+                           if spec is not None else params)
+            loss = (self.loss_fn(loss_params) if self.loss_fn is not None
+                    else jnp.zeros((), jnp.float32))
+        with jax.named_scope("sim.update"):
+            if spec is not None:
+                finite = jnp.all(jnp.isfinite(params))
+            else:
+                finite = jnp.array(True)
+                for leaf in jax.tree_util.tree_leaves(params):
+                    finite = jnp.logical_and(finite,
+                                             jnp.all(jnp.isfinite(leaf)))
         out = {
             "loss": loss,
             "participation": dec.mask,
@@ -377,7 +394,9 @@ class ClientSimulator:
 
         def chunk(c, _):
             c, outs = jax.lax.scan(body, c, None, length=eval_every)
-            return c, (outs, eval_fn(unflatten(c.params)))
+            with jax.named_scope("sim.eval"):
+                evals = eval_fn(unflatten(c.params))
+            return c, (outs, evals)
 
         carry, (outs, evals) = jax.lax.scan(
             chunk, carry, None, length=num_steps // eval_every)

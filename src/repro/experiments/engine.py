@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.trainer import ClientSimulator, SimHistory
 from repro.experiments.scenario import Scenario
 
@@ -424,6 +425,7 @@ def _record_downgrade(group, stage, frm, to, err) -> DowngradeRecord:
     return rec
 
 
+@tracing.span("engine.execute")
 def execute_cells(
     scenarios: Sequence[Scenario],
     *,
@@ -488,6 +490,12 @@ def execute_cells(
     bound, and evict. This is how :class:`repro.serve.StudyService`
     turns repeat traffic into pure dispatch while keeping executable
     memory bounded.
+
+    Host spans (:mod:`repro.tracing`): ``engine.execute`` around the
+    call, ``engine.resolve`` around grouping, and for each group
+    ``engine.dispatch`` and ``engine.collect`` (slicing out its cells),
+    which holds ``engine.wait`` (the group's first read, until its
+    program has run).
     """
     scenarios = list(scenarios)
     del _LAST_DOWNGRADES[:]
@@ -533,7 +541,8 @@ def execute_cells(
     if sharded:
         from repro.experiments import placement
 
-    _, _, groups = resolve_structure_groups(scenarios, sim=sim)
+    with tracing.span("engine.resolve"):
+        _, _, groups = resolve_structure_groups(scenarios, sim=sim)
 
     results: list[CellResult | None] = [None] * len(scenarios)
     for grp in groups:
@@ -550,37 +559,44 @@ def execute_cells(
                               num_steps=num_steps, eval_fn=eval_fn,
                               eval_every=eval_every)
 
-        if sharded:
-            member_names = [names[i] for i in grp.members]
-            reduction = client_reduction
-            while True:
-                try:
-                    out = placement.run_group_sharded(
-                        grp.scheduler, grp.energy, grp.active, grp.p, params0,
-                        keys, sim=sim, num_steps=num_steps,
-                        n_scenarios=len(grp.members), mesh=mesh,
-                        eval_fn=eval_fn, eval_every=eval_every,
-                        reduction=reduction, faults=grp.faults)
-                    break
-                except ValueError as e:
-                    if not degrade:
-                        raise
-                    lower = _REDUCTION_LADDER.get(reduction, ())
-                    if lower:
-                        _record_downgrade(member_names, "reduction",
-                                          reduction, lower[0], e)
-                        reduction = lower[0]
-                        continue
-                    _record_downgrade(member_names, "placement",
-                                      "sharded", "vmap", e)
-                    out = run_vmap()
-                    break
-        else:
-            out = run_vmap()
-        for j, idx in enumerate(grp.members):
-            cell = jax.tree_util.tree_map(lambda x: x[j], out)
-            cell = _crop_cell(cell, scenarios[idx].n_clients, n_cap)
-            results[idx] = _attach_divergence(cell)
+        with tracing.span("engine.dispatch"):
+            if sharded:
+                member_names = [names[i] for i in grp.members]
+                reduction = client_reduction
+                while True:
+                    try:
+                        out = placement.run_group_sharded(
+                            grp.scheduler, grp.energy, grp.active, grp.p,
+                            params0, keys, sim=sim, num_steps=num_steps,
+                            n_scenarios=len(grp.members), mesh=mesh,
+                            eval_fn=eval_fn, eval_every=eval_every,
+                            reduction=reduction, faults=grp.faults)
+                        break
+                    except ValueError as e:
+                        if not degrade:
+                            raise
+                        lower = _REDUCTION_LADDER.get(reduction, ())
+                        if lower:
+                            _record_downgrade(member_names, "reduction",
+                                              reduction, lower[0], e)
+                            reduction = lower[0]
+                            continue
+                        _record_downgrade(member_names, "placement",
+                                          "sharded", "vmap", e)
+                        out = run_vmap()
+                        break
+            else:
+                out = run_vmap()
+        with tracing.span("engine.collect"):
+            for j, idx in enumerate(grp.members):
+                cell = jax.tree_util.tree_map(lambda x: x[j], out)
+                cell = _crop_cell(cell, scenarios[idx].n_clients, n_cap)
+                if j == 0 and cell.history.finite is not None:
+                    # The group's first read (_attach_divergence's
+                    # np.asarray) waits for its program: named here.
+                    with tracing.span("engine.wait"):
+                        jax.block_until_ready(cell.history.finite)
+                results[idx] = _attach_divergence(cell)
     return dict(zip(names, results))
 
 

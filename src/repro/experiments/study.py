@@ -35,6 +35,7 @@ from typing import Any, Callable, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro._lru import LRUCache
 from repro.core.trainer import ClientSimulator
 from repro.experiments import engine
@@ -297,6 +298,7 @@ class Study:
             engine.clear_cache()
         return stats
 
+    @tracing.span("study.run")
     def run(self, *, params0, grads_fn=None, p=None, optimizer=None,
             loss_fn=None, use_kernel: bool = False,
             sim: ClientSimulator | None = None,
@@ -306,7 +308,8 @@ class Study:
         Pass either a prebuilt ``sim`` or the simulator ingredients
         (``grads_fn`` / ``p`` / ``optimizer`` [+ ``loss_fn`` /
         ``use_kernel``] — memoized, see :meth:`simulator`). Everything
-        about *how* to execute lives in ``config``.
+        about *how* to execute lives in ``config``. The call is one
+        ``study.run`` record of :mod:`repro.tracing`.
         """
         cfg = config or ExecutionConfig()
         if sim is None:

@@ -156,6 +156,7 @@ def masked_scaled_aggregate_kernel(g, w, mask=None, *, block_p: int = 2048,
     if mask is None:
         out = pl.pallas_call(
             _agg_kernel,
+            name="masked_scaled_aggregate",
             grid=(pp // bp,),
             in_specs=[vec_spec, g_spec],
             out_specs=o_spec,
@@ -167,6 +168,7 @@ def masked_scaled_aggregate_kernel(g, w, mask=None, *, block_p: int = 2048,
         m_op = mask.reshape(1, n).astype(jnp.float32)
         out = pl.pallas_call(
             _agg_kernel_masked,
+            name="masked_scaled_aggregate_masked",
             grid=(pp // bp,),
             in_specs=[vec_spec, vec_spec, g_spec],
             out_specs=o_spec,
@@ -227,6 +229,7 @@ def masked_scaled_aggregate_update_kernel(g, w, eta, params=None, mask=None,
     if params is None:
         out = pl.pallas_call(
             _agg_delta_kernel,
+            name="masked_scaled_aggregate_update_delta",
             grid=(pp // bp,),
             in_specs=[scalar_spec, vec_spec, vec_spec, g_spec],
             out_specs=tile_spec,
@@ -240,6 +243,7 @@ def masked_scaled_aggregate_update_kernel(g, w, eta, params=None, mask=None,
             p_op = jnp.pad(p_op, ((0, 0), (0, pad)))
         out = pl.pallas_call(
             _agg_update_kernel,
+            name="masked_scaled_aggregate_update",
             grid=(pp // bp,),
             in_specs=[scalar_spec, vec_spec, vec_spec, g_spec, tile_spec],
             out_specs=tile_spec,
