@@ -19,6 +19,12 @@ identical:
    jnp matvec per step — instead of one kernel launch (each with its
    own lane padding) per parameter leaf — and unraveled by offset
    slicing. Mixed-dtype pytrees fall back to the per-leaf path.
+   The simulator takes this path for gradients that arrive as one
+   ``(N, ...)`` array, or that faults or a client mesh axis act on as
+   flat rows (``psum``/``fused``); a multi-leaf gradient tree
+   (:func:`reduces_leafwise`) is reduced by path 1 where it lies, after
+   :func:`gather_client_rows` under ``gather``, and only its ``(P,)``
+   aggregate is raveled, so no ``(N, P)`` copy of it is ever written.
 3. ``per_example_coefficients`` — the *SPMD path* for framework-scale
    training: instead of materializing N per-client gradients, each example
    in the global batch carries the coefficient of its owning client, and
@@ -30,8 +36,9 @@ The raveler is shared infrastructure: :class:`repro.core.trainer.
 ClientSimulator` keeps its whole scan carry (params + optimizer state)
 in the flat space, so the per-step loop never round-trips the pytree
 leaf-by-leaf. The ravel boundary itself sits at the gradient source —
-:func:`make_flat_grads_fn` wraps any ``grads_fn`` into a flat ``(N, P)``
-emitter (and shards it along a client mesh axis, DESIGN.md §8);
+:func:`flatten_client_grads` turns any ``grads_fn`` output into a flat
+``(N, P)`` buffer, and :func:`shard_client_grads` calls a ``grads_fn``
+for one shard of a client mesh axis (DESIGN.md §8);
 :func:`reduce_flat_client_sharded` is the cross-shard reduction with the
 server update left replicated.
 """
@@ -218,77 +225,101 @@ def accepts_clients_kwarg(grads_fn) -> bool:
     return "clients" in sig.parameters
 
 
-def make_flat_grads_fn(grads_fn, spec: RavelSpec, n_clients: int):
-    """RavelSpec-aware wrapper: ``grads_fn`` → flat ``(N, P)`` emitter.
+def flatten_client_grads(stacked, spec: RavelSpec, n_rows: int) -> jax.Array:
+    """Client-stacked ``grads_fn`` output → one flat ``(n_rows, P)``
+    buffer in the layout of ``spec``.
 
-    The ravel boundary lives *here*, at the gradient source, so the scan
-    body carries no per-leaf concat: the wrapped function returns the
-    flat client-stacked buffer directly, whether ``grads_fn`` emits
-
-    * a client-stacked pytree mirroring the parameter tree (raveled via
-      a cached spec; uniform-dtype gradient trees that differ from the
-      params dtype stay in their own dtype, mixed-dtype trees are cast
-      to the params dtype — accumulation in the reduce is f32-or-better
-      either way), or
-    * a single ``(N, ...)`` array — already flat up to a reshape (the
-      natively-flat fast path; single-leaf parameter trees land here).
-
-    Under an active client-sharding context (DESIGN.md §8) the wrapper
-    returns this shard's ``(n_local, P)`` rows: a client-aware grads_fn
-    (:func:`accepts_clients_kwarg`) is called with the shard's global
-    client indices; a plain grads_fn is called in full — row-sliced
-    (bitwise the rows of the unsharded call) under ``psum``, but handed
-    over *whole* under ``gather``, where every shard already holds the
-    identical replicated buffer and slicing it apart only for
-    ``all_gather`` to reassemble it would be a pure N·P round trip
-    (:func:`reduce_flat_client_sharded` skips the gradient gather on
-    full-width input).
+    The grads_fn may emit a client-stacked pytree mirroring the
+    parameter tree (raveled via its spec; uniform-dtype gradient trees
+    that differ from the params dtype stay in their own dtype,
+    mixed-dtype trees are cast to the params dtype — accumulation in
+    the reduce is f32-or-better either way), or a single ``(N, ...)``
+    array — already flat up to a reshape (the natively-flat fast path;
+    single-leaf parameter trees land here).
     """
-    from repro.core.energy import client_shard
-
-    accepts = accepts_clients_kwarg(grads_fn)
-
-    def flatten(stacked, n_rows):
-        if isinstance(stacked, jax.Array):
-            g = stacked.reshape(n_rows, -1)
-            if g.shape[1] != spec.total:
-                raise ValueError(
-                    f"flat grads_fn output has {g.shape[1]} parameters per "
-                    f"client; the parameter pytree has {spec.total}")
-            return g
-        try:
-            gspec = ravel_spec(stacked, lead_axes=1)
-        except ValueError:
-            # Mixed-dtype gradients (e.g. one layer computed in bf16)
-            # against uniform-dtype params: aggregate in the params
-            # dtype — accumulation inside reduce_flat is f32-or-better
-            # either way.
-            stacked = jax.tree_util.tree_map(
-                lambda x: x.astype(spec.dtype), stacked)
-            gspec = ravel_spec(stacked, lead_axes=1)
-        if gspec.shapes != spec.shapes or gspec.treedef != spec.treedef:
+    if isinstance(stacked, jax.Array):
+        g = stacked.reshape(n_rows, -1)
+        if g.shape[1] != spec.total:
             raise ValueError(
-                "grads_fn output does not mirror the parameter pytree; "
-                "flat-carry execution needs matching structure+shapes "
-                f"(params {spec.shapes}, grads {gspec.shapes})")
-        return ravel_stacked(stacked, gspec)
+                f"flat grads_fn output has {g.shape[1]} parameters per "
+                f"client; the parameter pytree has {spec.total}")
+        return g
+    try:
+        gspec = ravel_spec(stacked, lead_axes=1)
+    except ValueError:
+        # Mixed-dtype gradients (e.g. one layer computed in bf16)
+        # against uniform-dtype params: aggregate in the params
+        # dtype — accumulation inside reduce_flat is f32-or-better
+        # either way.
+        stacked = jax.tree_util.tree_map(
+            lambda x: x.astype(spec.dtype), stacked)
+        gspec = ravel_spec(stacked, lead_axes=1)
+    if gspec.shapes != spec.shapes or gspec.treedef != spec.treedef:
+        raise ValueError(
+            "grads_fn output does not mirror the parameter pytree; "
+            "flat-carry execution needs matching structure+shapes "
+            f"(params {spec.shapes}, grads {gspec.shapes})")
+    return ravel_stacked(stacked, gspec)
 
-    def flat_grads(params, key, t):
-        shard = client_shard()
-        if shard is None:
-            return flatten(grads_fn(params, key, t), n_clients)
-        n_local = n_clients // shard.shards
-        if accepts:
-            idx = (jax.lax.axis_index(shard.axis_name) * n_local
-                   + jnp.arange(n_local, dtype=jnp.int32))
-            return flatten(grads_fn(params, key, t, clients=idx), n_local)
-        full = flatten(grads_fn(params, key, t), n_clients)
-        if parse_reduction(shard.reduction)[0] == "gather":
-            return full
-        off = jax.lax.axis_index(shard.axis_name) * n_local
-        return jax.lax.dynamic_slice_in_dim(full, off, n_local, axis=0)
 
-    return flat_grads
+def reduces_leafwise(stacked, spec: RavelSpec) -> bool:
+    """Whether client-stacked gradients are reduced leaf by leaf.
+
+    True for a float32 tree of more than one leaf that mirrors the
+    float32 parameter layout ``spec``: :func:`aggregate_client_grads`
+    then reduces each leaf where it lies, and only the ``(P,)``
+    aggregate is raveled. Raveling such a tree into ``(N, P)`` costs a
+    copy of every leaf (a relayout of transposed leaves, then unaligned
+    writes at the leaf offsets) before the reduction reads it once. A
+    single array is already flat up to a free reshape; other or mixed
+    dtypes take the flat buffer, which casts and upcasts them.
+    """
+    leaves, treedef = jax.tree_util.tree_flatten(stacked)
+    return (len(leaves) > 1 and treedef == spec.treedef
+            and spec.dtype == jnp.float32
+            and all(leaf.dtype == spec.dtype and leaf.shape[1:] == shape
+                    for leaf, shape in zip(leaves, spec.shapes)))
+
+
+def shard_client_grads(grads_fn, params, key, t, n_clients: int, shard):
+    """This shard's client-stacked gradients under a clients mesh axis
+    (DESIGN.md §8), as ``(stacked, n_rows)``.
+
+    A client-aware grads_fn (:func:`accepts_clients_kwarg`) is called
+    with the shard's global client indices and returns its ``n_local``
+    rows; a plain grads_fn is called in full — row-sliced (bitwise the
+    rows of the unsharded call) under ``psum``/``fused``, but handed
+    over *whole* under ``gather``, where every shard already holds the
+    identical replicated rows and slicing them apart only for
+    ``all_gather`` to reassemble them would be a pure N·P round trip
+    (:func:`gather_client_rows` skips the gather on full-width rows).
+    """
+    n_local = n_clients // shard.shards
+    if accepts_clients_kwarg(grads_fn):
+        idx = (jax.lax.axis_index(shard.axis_name) * n_local
+               + jnp.arange(n_local, dtype=jnp.int32))
+        return grads_fn(params, key, t, clients=idx), n_local
+    full = grads_fn(params, key, t)
+    if parse_reduction(shard.reduction)[0] == "gather":
+        return full, n_clients
+    off = jax.lax.axis_index(shard.axis_name) * n_local
+    return jax.tree_util.tree_map(
+        lambda x: jax.lax.dynamic_slice_in_dim(x, off, n_local, axis=0),
+        full), n_local
+
+
+def gather_client_rows(stacked, weights: jax.Array, mask, axis_name: str):
+    """Reassemble the global client rows of ``gather`` (DESIGN.md §8):
+    all_gather (tiled, so in exact row order) the weights, the mask and
+    every gradient leaf not already at full population width, so each
+    shard can replay the identical unsharded reduction."""
+    weights = jax.lax.all_gather(weights, axis_name, axis=0, tiled=True)
+    if mask is not None:
+        mask = jax.lax.all_gather(mask, axis_name, axis=0, tiled=True)
+    stacked = jax.tree_util.tree_map(
+        lambda x: x if x.shape[0] == weights.shape[0] else
+        jax.lax.all_gather(x, axis_name, axis=0, tiled=True), stacked)
+    return stacked, weights, mask
 
 
 # ----------------------------------------------------- aggregation paths
@@ -377,7 +408,7 @@ def reduce_flat_client_sharded(g: jax.Array, weights: jax.Array, *,
       the single-device result; costs N_local·P per device per step of
       interconnect. A ``g`` already at full population width (a plain
       grads_fn computes it replicated on every shard —
-      :func:`make_flat_grads_fn`) skips the gradient gather; only the
+      :func:`shard_client_grads`) skips the gradient gather; only the
       (N,)-sized weights/mask cross the axis.
     * ``"psum"`` — one local matvec/kernel launch over this shard's rows
       followed by a ``(P,)`` cross-shard sum. Bandwidth-optimal (the
@@ -403,11 +434,7 @@ def reduce_flat_client_sharded(g: jax.Array, weights: jax.Array, *,
     if mode == "gather":
         if wire_dtype is not None:
             raise ValueError("gather is bitwise; wire_dtype is not allowed")
-        weights = jax.lax.all_gather(weights, axis_name, axis=0, tiled=True)
-        if mask is not None:
-            mask = jax.lax.all_gather(mask, axis_name, axis=0, tiled=True)
-        if g.shape[0] != weights.shape[0]:
-            g = jax.lax.all_gather(g, axis_name, axis=0, tiled=True)
+        g, weights, mask = gather_client_rows(g, weights, mask, axis_name)
         out = reduce_flat(g, weights, use_kernel=use_kernel,
                           out_dtype=out_dtype, mask=mask)
         return out, jnp.sum(weights)

@@ -23,6 +23,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core import aggregation
 from repro.core.energy import client_shard
 from repro.core.scheduling import Decision
@@ -70,7 +71,14 @@ class ClientSimulator:
     optimizer : repro.optim.Optimizer applied to the aggregated update.
         For exact paper semantics use ``sgd(eta)``.
     loss_fn : optional (params) -> scalar global loss, logged per step.
-    use_kernel : route aggregation through the Pallas kernel path.
+    use_kernel : route aggregation through the Pallas kernel path. The
+        kernel serves gradients that arrive as one flat buffer: a single
+        ``(N, ...)`` array, or the flat rows that faults or the ``psum``
+        and ``fused`` client reductions act on. A multi-leaf gradient
+        tree in a flat-carry, fault-free step, unsharded or under
+        ``gather``, is reduced leaf by leaf whatever this says
+        (DESIGN.md §5): raveling it into ``(N, P)`` for the kernel would
+        cost more than the kernel's one read.
 
     Under an active client-sharding context (DESIGN.md §8 — entered by
     the placement layer's ``run_client_sharded`` / ``clients``-mesh grid
@@ -101,7 +109,6 @@ class ClientSimulator:
         self.loss_fn = loss_fn
         self.use_kernel = use_kernel
         self.flat = flat
-        self._gfn_cache: dict = {}
 
     def _components(self, scheduler, energy):
         scheduler = self.scheduler if scheduler is None else scheduler
@@ -134,16 +141,6 @@ class ClientSimulator:
         it to :meth:`init` / :meth:`run_carry` so a saved flat
         :class:`SimCarry` resumes in the same layout."""
         return self._flat_spec(params)
-
-    def _flat_grads(self, spec):
-        """Memoized RavelSpec-aware grads wrapper (the ravel boundary —
-        :func:`repro.core.aggregation.make_flat_grads_fn`)."""
-        fn = self._gfn_cache.get(spec)
-        if fn is None:
-            fn = aggregation.make_flat_grads_fn(
-                self.grads_fn, spec, int(self.p.shape[0]))
-            self._gfn_cache[spec] = fn
-        return fn
 
     def init(self, key, params, *, scheduler=None, energy=None,
              faults=None, spec=None) -> SimCarry:
@@ -241,10 +238,22 @@ class ClientSimulator:
         if spec is not None:
             with jax.named_scope("sim.grads"):
                 params_tree = aggregation.unravel_pytree(carry.params, spec)
-                # The ravel boundary lives inside the wrapper: the scan
-                # body sees one flat (N, P) — or, sharded, (n_local, P) —
-                # buffer and carries no per-leaf concat.
-                g = self._flat_grads(spec)(params_tree, k_grad, carry.t)
+                n_rows = int(self.p.shape[0])
+                if shard is None:
+                    stacked = self.grads_fn(params_tree, k_grad, carry.t)
+                else:
+                    stacked, n_rows = aggregation.shard_client_grads(
+                        self.grads_fn, params_tree, k_grad, carry.t,
+                        n_rows, shard)
+                # Faults and the psum/fused reductions act on flat rows;
+                # otherwise a gradient tree is reduced where it lies
+                # (g None) and no (N, P) copy of it is written.
+                leafwise = (faults is None and (
+                    shard is None or aggregation.parse_reduction(
+                        shard.reduction)[0] == "gather")
+                    and aggregation.reduces_leafwise(stacked, spec))
+                g = (None if leafwise else aggregation.flatten_client_grads(
+                    stacked, spec, n_rows))
             if faults is not None:
                 # Delivery faults transform the flat rows and/or return a
                 # keep mask; keep composes into the active-row select so
@@ -260,7 +269,30 @@ class ClientSimulator:
                     weights = weights * keep
                     row_mask = aggregation.compose_masks(active_mask, keep)
             with jax.named_scope("sim.update"):
-                if shard is not None:
+                if g is None:
+                    tracing.count("sim.update_leafwise")
+                    if shard is not None:
+                        # gather: every shard replays the unsharded
+                        # reduction over the reassembled rows, bitwise
+                        # the single-device step.
+                        stacked, weights, row_mask = \
+                            aggregation.gather_client_rows(
+                                stacked, weights, row_mask, shard.axis_name)
+                    agg = aggregation.ravel_pytree(
+                        aggregation.aggregate_client_grads(
+                            stacked, weights, row_mask), spec)
+                    if fusable:
+                        # Plain sgd steps through the fused op on every
+                        # flat-carry path (the benchmark's fault check
+                        # replaces it): the aggregate as a one-client
+                        # stack of unit weight, whose jnp branch is the
+                        # kernel's params − η·agg.
+                        params, opt_state, _ = \
+                            aggregation.fused_flat_sgd_update(
+                                agg[None, :], jnp.ones((1,), jnp.float32),
+                                carry.params, carry.opt_state,
+                                self.optimizer)
+                elif shard is not None:
                     mode, wire = aggregation.parse_reduction(shard.reduction)
                     if mode == "fused":
                         if not fusable:
@@ -360,12 +392,14 @@ class ClientSimulator:
 
         When the parameter pytree has a single leaf dtype (``flat``
         mode, the default), the scan carry holds params and optimizer
-        state as single flat buffers: per step the loop issues exactly
-        one aggregation kernel/matvec over the whole ``(N, P)`` gradient
-        buffer and never round-trips optimizer state leaf-by-leaf; the
-        pytree view exists only at the grads_fn/loss_fn/eval_fn
-        boundaries (cheap slices/reshapes XLA fuses away). The returned
-        ``final_params`` is always the original pytree structure.
+        state as single flat buffers and never round-trips optimizer
+        state leaf-by-leaf. Per step, gradients that arrive flat take
+        exactly one aggregation kernel/matvec over the whole ``(N, P)``
+        buffer; a multi-leaf gradient tree is reduced leaf by leaf and
+        only its ``(P,)`` aggregate raveled. The pytree view exists only
+        at the grads_fn/loss_fn/eval_fn boundaries (cheap
+        slices/reshapes XLA fuses away). The returned ``final_params``
+        is always the original pytree structure.
         """
         scheduler, energy = self._components(scheduler, energy)
         faults = self._fault(faults)

@@ -10,12 +10,15 @@ module always runs.
 """
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import tracing
 from repro.core import ClientSimulator, aggregation, make_quadratic, make_scheduler
 from repro.core.energy import BinaryArrivals, DeterministicArrivals
+from repro.core.faults import DropUpdates
 from repro.kernels.aggregate import ops as agg_ops
 from repro.optim import adam, sgd
 
@@ -191,61 +194,189 @@ def test_flat_carry_run_matches_legacy(opt, use_kernel):
                                    rtol=2e-4, atol=1e-5)
 
 
-def test_flat_carry_one_kernel_call_per_step(monkeypatch):
-    """Tracing the whole scan loop with use_kernel=True must hit the
-    kernel entry point exactly once — one launch per step regardless of
-    the number of parameter leaves. A tagged plain sgd() optimizer
-    routes through the *fused* reduce-and-update op (DESIGN.md §9); the
-    unfused reduce must not run at all on that path."""
-    calls = []
-    real = agg_ops.masked_scaled_aggregate_update
+def _gradient_source(arrives: str, n: int):
+    """``(params, grads_fn, faults)`` of the dict problem with its client
+    gradients delivered as the parameter tree, as one ``(N, P)`` array,
+    or as the tree under a (rate-0) fault component."""
+    params, grads_fn, _ = _dict_problem(n)
+    if arrives == "array":
+        return params, (lambda p, k, t: aggregation.ravel_stacked(
+            grads_fn(p, k, t))), None
+    return params, grads_fn, (DropUpdates(0.0) if arrives == "faults"
+                              else None)
 
-    def counting(g, w, *a, **kw):
-        calls.append(g.shape)
-        return real(g, w, *a, **kw)
 
-    def no_unfused(*a, **kw):
-        raise AssertionError("unfused reduce reached on the fused sgd path")
+def _jaxpr_values(jaxpr) -> list:
+    """``(primitive, shape)`` of every value an equation of ``jaxpr`` (or
+    of a jaxpr nested in one) produces."""
+    values = []
+    for eqn in jaxpr.eqns:
+        values += [(eqn.primitive.name, tuple(v.aval.shape))
+                   for v in eqn.outvars]
+        for v in eqn.params.values():
+            for sub in jax.tree_util.tree_leaves(
+                    v, is_leaf=lambda x: isinstance(
+                        x, (jex_core.Jaxpr, jex_core.ClosedJaxpr))):
+                if isinstance(sub, jex_core.ClosedJaxpr):
+                    values += _jaxpr_values(sub.jaxpr)
+                elif isinstance(sub, jex_core.Jaxpr):
+                    values += _jaxpr_values(sub)
+    return values
 
-    monkeypatch.setattr(agg_ops, "masked_scaled_aggregate_update", counting)
-    monkeypatch.setattr(agg_ops, "masked_scaled_aggregate", no_unfused)
+
+def _kernel_launches(monkeypatch, opt, arrives: str):
+    """Trace a ``use_kernel=True`` run of the dict problem with ``opt``
+    and gradients arriving as ``arrives``; returns the ``(N, P)`` shape,
+    the shapes the fused / unfused kernel entries were called with, and
+    ``(primitive, shape)`` of every value of the traced run."""
+    calls = {"fused": [], "unfused": []}
+    for name, entry in (("fused", "masked_scaled_aggregate_update"),
+                        ("unfused", "masked_scaled_aggregate")):
+        real = getattr(agg_ops, entry)
+
+        def counting(g, w, *a, _real=real, _calls=calls[name], **kw):
+            _calls.append(g.shape)
+            return _real(g, w, *a, **kw)
+
+        monkeypatch.setattr(agg_ops, entry, counting)
     n = 4
-    params, grads_fn, loss_fn = _dict_problem(n)
+    params, grads_fn, faults = _gradient_source(arrives, n)
     sim = ClientSimulator(
         grads_fn=grads_fn, scheduler=make_scheduler("alg1", n),
         energy=BinaryArrivals([0.5] * n), p=jnp.full((n,), 0.25),
-        optimizer=sgd(0.05), use_kernel=True)
-    sim.run(jax.random.PRNGKey(0), params, 10)
-    # The scan body traces once; a per-leaf implementation would record
-    # len(params) == 3 shapes here.
+        optimizer=opt, faults=faults, use_kernel=True)
+    jaxpr = jax.make_jaxpr(lambda k, p0: sim.run(k, p0, 10))(
+        jax.random.PRNGKey(0), params)
     total = 3 * 5 + 7 + 2 * 3 * 5
-    assert calls == [(n, total)]
+    return (n, total), calls, _jaxpr_values(jaxpr.jaxpr)
 
 
-def test_flat_carry_stateful_optimizer_keeps_unfused_kernel(monkeypatch):
-    """adam (untagged) must keep the reduce → update split: exactly one
-    unfused kernel launch per step, never the fused sgd op."""
-    calls = []
-    real = agg_ops.masked_scaled_aggregate
+@pytest.mark.parametrize("arrives", ["tree", "array", "faults"])
+def test_flat_carry_one_kernel_call_per_step(monkeypatch, arrives):
+    """With use_kernel=True and a tagged plain sgd() optimizer, gradients
+    that arrive flat (one array, or rows a fault component acts on) take
+    exactly one fused reduce-and-update launch over ``(N, P)`` per step
+    (DESIGN.md §9) and never the unfused reduce. A gradient tree is
+    reduced leaf by leaf: no Pallas launch, and no ``(N, P)`` value
+    anywhere in the traced step."""
+    np_shape, calls, values = _kernel_launches(monkeypatch, sgd(0.05),
+                                               arrives)
+    assert calls["unfused"] == []
+    if arrives == "tree":
+        assert calls["fused"] == []
+        assert not [v for v in values
+                    if v[0] == "pallas_call" or v[1] == np_shape]
+    else:
+        # The scan body traces once; a per-leaf implementation would
+        # record len(params) == 3 shapes here.
+        assert calls["fused"] == [np_shape]
 
-    def counting(g, w, *a, **kw):
-        calls.append(g.shape)
-        return real(g, w, *a, **kw)
 
-    def no_fused(*a, **kw):
-        raise AssertionError("fused sgd op reached with a stateful optimizer")
+@pytest.mark.parametrize("arrives", ["tree", "array", "faults"])
+def test_flat_carry_stateful_optimizer_keeps_unfused_kernel(monkeypatch,
+                                                            arrives):
+    """adam (untagged) must keep the reduce → update split: gradients
+    that arrive flat take exactly one unfused kernel launch per step,
+    never the fused sgd op; a gradient tree takes no launch and makes
+    no ``(N, P)`` value."""
+    np_shape, calls, values = _kernel_launches(monkeypatch, adam(0.05),
+                                               arrives)
+    assert calls["fused"] == []
+    if arrives == "tree":
+        assert calls["unfused"] == []
+        assert not [v for v in values
+                    if v[0] == "pallas_call" or v[1] == np_shape]
+    else:
+        assert calls["unfused"] == [np_shape]
 
-    monkeypatch.setattr(agg_ops, "masked_scaled_aggregate", counting)
-    monkeypatch.setattr(agg_ops, "masked_scaled_aggregate_update", no_fused)
+
+@pytest.mark.parametrize("arrives", ["tree", "array", "faults"])
+def test_leafwise_update_is_counted_on_the_tree_path_only(arrives):
     n = 4
-    params, grads_fn, loss_fn = _dict_problem(n)
+    params, grads_fn, faults = _gradient_source(arrives, n)
     sim = ClientSimulator(
         grads_fn=grads_fn, scheduler=make_scheduler("alg1", n),
         energy=BinaryArrivals([0.5] * n), p=jnp.full((n,), 0.25),
-        optimizer=adam(0.05), use_kernel=True)
-    sim.run(jax.random.PRNGKey(0), params, 10)
-    total = 3 * 5 + 7 + 2 * 3 * 5
-    assert calls == [(n, total)]
+        optimizer=sgd(0.05), faults=faults, use_kernel=True)
+    with tracing.span("test.run") as record:
+        sim.run(jax.random.PRNGKey(0), params, 3)
+    if arrives == "tree":
+        # Counted at trace time, once for the one traced step body, and
+        # with no note: readers take a record's notes for its compiles.
+        assert record.counters["sim.update_leafwise"] == 1
+        assert all(nt["counter"] in ("compiles", "cache_loads")
+                   for nt in record.notes)
+    else:
+        assert "sim.update_leafwise" not in record.counters
+
+
+# --------------------------------- tree gradients, reduced leaf by leaf
+
+#: A ragged population: the last two of six rows do not exist.
+LEAFWISE_ACTIVE = jnp.array([1.0, 1.0, 1.0, 1.0, 0.0, 0.0])
+
+
+def _poisoned_problem(poison: bool):
+    """The dict problem over six rows whose two inactive rows are inf and
+    NaN (``poison``) or zeroed by hand."""
+    n = LEAFWISE_ACTIVE.shape[0]
+    params, grads_fn, loss_fn = _dict_problem(n)
+    fill = jnp.array([0.0, 0.0, 0.0, 0.0, jnp.inf, jnp.nan]) if poison \
+        else jnp.zeros((n,))
+
+    def poisoned(p, key, t):
+        return jax.tree_util.tree_map(
+            lambda g: jnp.where(
+                LEAFWISE_ACTIVE.reshape((-1,) + (1,) * (g.ndim - 1)) > 0,
+                g, fill.reshape((-1,) + (1,) * (g.ndim - 1))),
+            grads_fn(p, key, t))
+
+    return params, poisoned, loss_fn
+
+
+def _ragged_run(grads_fn, params, loss_fn, opt, use_kernel, flat=None):
+    n = LEAFWISE_ACTIVE.shape[0]
+    sim = ClientSimulator(
+        grads_fn=grads_fn, scheduler=make_scheduler("alg1", n),
+        energy=BinaryArrivals([0.7] * n),
+        p=LEAFWISE_ACTIVE / jnp.sum(LEAFWISE_ACTIVE), optimizer=opt,
+        loss_fn=loss_fn, use_kernel=use_kernel, flat=flat)
+    w, h = sim.run(jax.random.PRNGKey(7), params, 8,
+                   active_mask=LEAFWISE_ACTIVE)
+    return jax.tree_util.tree_map(np.asarray, (w, h))
+
+
+@pytest.mark.parametrize("opt", [sgd(0.05), adam(0.05)], ids=["sgd", "adam"])
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["matvec", "kernel"])
+def test_leafwise_step_matches_oracle_and_flat_buffer(opt, use_kernel):
+    """A tree-gradient run over a ragged population with inf/NaN in its
+    masked rows: the leafwise step matches the per-leaf oracle
+    (``flat=False``) and the flat-buffer path (the same gradients
+    delivered as one ``(N, P)`` array) to f32 tolerance over eight
+    rounds; poisoning the masked rows is bitwise the same as zeroing them
+    by hand; ``weight_sum`` is bitwise the flat path's."""
+    params, poisoned, loss_fn = _poisoned_problem(True)
+    _, zeroed, _ = _poisoned_problem(False)
+    w, h = _ragged_run(poisoned, params, loss_fn, opt, use_kernel)
+    w0, h0 = _ragged_run(zeroed, params, loss_fn, opt, use_kernel)
+    for a, b in zip(jax.tree_util.tree_leaves((w, h)),
+                    jax.tree_util.tree_leaves((w0, h0))):
+        np.testing.assert_array_equal(a, b)
+    assert np.all(np.isfinite(h.loss))
+    assert h.participation[:, 4:].sum() == 0
+    oracle = _ragged_run(poisoned, params, loss_fn, opt, use_kernel,
+                         flat=False)
+    flat_buffer = _ragged_run(
+        lambda p, k, t: aggregation.ravel_stacked(poisoned(p, k, t)),
+        params, loss_fn, opt, use_kernel)
+    for w_ref, h_ref in (oracle, flat_buffer):
+        np.testing.assert_array_equal(h.weight_sum, h_ref.weight_sum)
+        np.testing.assert_array_equal(h.participation, h_ref.participation)
+        np.testing.assert_allclose(h.loss, h_ref.loss, rtol=2e-4, atol=1e-5)
+        for name in w:
+            np.testing.assert_allclose(w[name], w_ref[name],
+                                       rtol=2e-4, atol=1e-5)
 
 
 def test_flat_carry_tolerates_mixed_dtype_grads():

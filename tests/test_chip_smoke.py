@@ -47,7 +47,7 @@ def test_rehearsal_runs_train_and_serve_phases(smoke, monkeypatch, capsys):
     train, serve = phases["train"], phases["serve"]
     assert train["cells"] == 4 and train["rounds"] == smoke.ROUNDS
     assert train["compare_rounds"] == smoke.COMPARE_ROUNDS
-    assert train["kernel_vs_jnp"]["ok"] and train["tpu_custom_call"]
+    assert train["kernel_vs_leafwise"]["ok"] and train["tpu_custom_call"]
     for name, (round0, final) in train["loss_round0_final"].items():
         assert final < round0 or name.startswith("benchmark2")
     assert serve["compiles"] == 1 and serve["resubmit_compiles"] == 0

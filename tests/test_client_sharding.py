@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import tracing
 from repro.core import ClientSimulator, make_quadratic, scheduler_names
 from repro.core.energy import make_arrivals
 from repro.core.scheduling import make_scheduler
@@ -174,6 +175,60 @@ def test_single_cell_run_client_sharded_bitwise(sim, params0):
                                   np.asarray(hs.participation))
     np.testing.assert_array_equal(np.asarray(hu.weight_sum),
                                   np.asarray(hs.weight_sum))
+
+
+def _tree_sim(master, *, aware: bool) -> ClientSimulator:
+    """The quadratic with params ``{"a": (2,), "b": (DIM-2,)}``: client
+    gradients arrive as a two-leaf tree. ``aware`` makes the grads_fn
+    client-aware, returning the selected rows of the full call."""
+    w_star = master.w_star
+
+    def joined(w):
+        return jnp.concatenate([w["a"], w["b"]])
+
+    def grads_fn(w, k, t, clients=None):
+        g = master.all_grads(joined(w))
+        if clients is not None:
+            g = g[clients]
+        return {"a": g[:, :2], "b": g[:, 2:]}
+
+    return ClientSimulator(
+        grads_fn=grads_fn if aware else (lambda w, k, t: grads_fn(w, k, t)),
+        p=master.p, optimizer=sgd(0.02),
+        loss_fn=lambda w: jnp.sum((joined(w) - w_star) ** 2))
+
+
+@clientshard
+@multidevice
+@pytest.mark.parametrize("reduction,aware", [
+    ("gather", False), ("gather", True), ("psum", False), ("fused", True)])
+def test_tree_gradients_client_sharded(master, reduction, aware):
+    """Gradients that arrive as a tree: ``gather`` reassembles the rows
+    of every leaf and replays the unsharded leafwise reduction, bitwise
+    the single-device run; ``psum``/``fused`` reduce the flat rows, to
+    f32 tolerance."""
+    sim = _tree_sim(master, aware=aware)
+    params0 = {"a": jnp.full((2,), 4.0), "b": jnp.full((DIM - 2,), 4.0)}
+    scheduler = make_scheduler("alg2", N_CAP)
+    energy = make_arrivals("binary", N_CAP, 16)
+    key = jax.random.PRNGKey(3)
+    pu, hu = sim.run(key, params0, 15, scheduler=scheduler, energy=energy)
+    with tracing.span("test.sharded") as record:
+        ps, hs = run_client_sharded(sim, key, params0, 15,
+                                    scheduler=scheduler, energy=energy,
+                                    mesh=make_client_mesh(),
+                                    reduction=reduction)
+    assert ("sim.update_leafwise" in record.counters) == (
+        reduction == "gather")
+    np.testing.assert_array_equal(np.asarray(hu.participation),
+                                  np.asarray(hs.participation))
+    for a, b in zip(jax.tree_util.tree_leaves((pu, hu.loss, hu.weight_sum)),
+                    jax.tree_util.tree_leaves((ps, hs.loss, hs.weight_sum))):
+        if reduction == "gather":
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        else:
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-6)
 
 
 @clientshard

@@ -1,9 +1,9 @@
 """Property tests for the ravel boundary (DESIGN.md §5/§8).
 
-The flat gradient path now starts *inside* ``grads_fn`` — a
-RavelSpec-aware wrapper (:func:`repro.core.aggregation.
-make_flat_grads_fn`) emits the ``(N, P)`` buffer directly, so these
-properties pin the boundary itself: flatten/unflatten round-trip
+The flat gradient path starts at the ``grads_fn`` output —
+:func:`repro.core.aggregation.flatten_client_grads` turns it into the
+``(N, P)`` buffer the simulator step reduces, so these properties pin
+the boundary itself: flatten/unflatten round-trip
 identity over random nested pytree *structures* (not just flat dicts)
 and mixed-dtype rejection, plus exact-zero contribution of masked rows
 through the wrapped flat path even when the masked rows hold inf/NaN.
@@ -98,8 +98,8 @@ def test_mixed_dtype_trees_are_rejected(structure, seed, dtypes):
        n=st.integers(2, 8), use_kernel=st.booleans())
 def test_masked_rows_contribute_exact_zero_through_flat_grads_fn(
         structure, seed, n, use_kernel):
-    """The flat grads_fn path end-to-end: wrap a stacked-pytree grads_fn
-    with make_flat_grads_fn, poison the masked-out client rows with
+    """The flat grads_fn path end-to-end: flatten a stacked-pytree
+    grads_fn output with flatten_client_grads, poison the masked-out client rows with
     inf/NaN, and require the reduction to be *bitwise* the reduction of
     the clean rows — the mask is a row select, not a multiply."""
     key = jax.random.PRNGKey(seed)
@@ -116,13 +116,8 @@ def test_masked_rows_contribute_exact_zero_through_flat_grads_fn(
         clean)
     weights = jax.random.uniform(jax.random.fold_in(key, 4), (n,)) * mask
 
-    gfn_clean = aggregation.make_flat_grads_fn(lambda p, k, t: clean,
-                                               spec, n)
-    gfn_poison = aggregation.make_flat_grads_fn(lambda p, k, t: poison,
-                                                spec, n)
-    k = jax.random.PRNGKey(0)
-    g_clean = gfn_clean(params, k, 0)
-    g_poison = gfn_poison(params, k, 0)
+    g_clean = aggregation.flatten_client_grads(clean, spec, n)
+    g_poison = aggregation.flatten_client_grads(poison, spec, n)
     assert g_clean.shape == g_poison.shape == (n, spec.total)
 
     ref = aggregation.reduce_flat(g_clean, weights, mask=mask)
@@ -146,8 +141,7 @@ def test_flat_grads_fn_array_output_is_the_ravel(seed, n, dim):
     params = jax.random.normal(key, (dim,))
     spec = aggregation.ravel_spec(params)
     stacked = jax.random.normal(jax.random.fold_in(key, 1), (n, dim))
-    gfn = aggregation.make_flat_grads_fn(lambda p, k, t: stacked, spec, n)
-    out = gfn(params, jax.random.PRNGKey(0), 0)
+    out = aggregation.flatten_client_grads(stacked, spec, n)
     np.testing.assert_array_equal(
         np.asarray(out),
         np.asarray(aggregation.ravel_stacked(stacked,
