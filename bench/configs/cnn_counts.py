@@ -1,8 +1,9 @@
-"""Operations and bytes the algorithm needs, from the configuration's
-sizes: the CNN of ``configs/cnn_reference.py`` (its convolutions and
-dense layers; pooling and normalisation are not counted) and the fused
-server update of ``kernels/aggregate`` (read ``n`` client rows, the
-parameters, write the parameters; float32)."""
+"""Operations the algorithm needs for one image of the CNN of
+``cnn_reference.py``, from the configuration's sizes: its convolutions
+and dense layers (pooling and normalisation are not counted).
+
+``forward_flops(cfg)`` and ``train_flops(cfg)`` are what the benchmark's
+``step_mfu`` reads of any configuration's ``counts`` module."""
 
 from __future__ import annotations
 
@@ -22,7 +23,7 @@ def _widths(model: dict) -> list[int]:
     return [flat, *model["dense_widths"], model["n_classes"]]
 
 
-def cnn_layer_macs(model: dict) -> list[int]:
+def layer_macs(model: dict) -> list[int]:
     """Multiply-adds per image of each layer's forward pass: the SAME
     convolutions, then the dense layers and the logits."""
     k = model["kernel_size"]
@@ -34,29 +35,23 @@ def cnn_layer_macs(model: dict) -> list[int]:
     return convs + [a * b for a, b in zip(widths, widths[1:])]
 
 
-def cnn_forward_flops(model: dict) -> int:
+def forward_flops(cfg: dict) -> int:
     """FLOPs of one image's forward pass (2 per multiply-add)."""
-    return 2 * sum(cnn_layer_macs(model))
+    return 2 * sum(layer_macs(cfg["model"]))
 
 
-def cnn_train_flops(model: dict) -> int:
+def train_flops(cfg: dict) -> int:
     """FLOPs of one image's forward and backward pass: the forward, the
     weight gradient of every layer, and the input gradient of every
     layer but the first (whose input is the image)."""
-    macs = cnn_layer_macs(model)
+    macs = layer_macs(cfg["model"])
     return 2 * (3 * sum(macs) - macs[0])
 
 
-def cnn_params(model: dict) -> int:
+def params(model: dict) -> int:
     k = model["kernel_size"]
     c_in = [model["in_channels"], *model["conv_channels"]]
     convs = sum((k * k * c + 1) * c_out
                 for c, c_out in zip(c_in, model["conv_channels"]))
     widths = _widths(model)
     return convs + sum((a + 1) * b for a, b in zip(widths, widths[1:]))
-
-
-def update_bytes(n_params: int, n_rows: int) -> int:
-    """HBM bytes of one fused reduce-and-update over ``n_rows`` float32
-    client rows: the rows, the parameters in and the parameters out."""
-    return 4 * n_params * (n_rows + 2)

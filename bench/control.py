@@ -50,8 +50,7 @@ def main(argv=None) -> int:
           flush=True)
     schedulers = traffic.get("schedulers") or traffic["axes"]["scheduler"]
     sizes = traffic.get("n_clients") or [cfg["n_clients"]]
-    model = harness.load_module(HERE / "configs" / f"{cfg['reference']}.py",
-                                "control_reference")
+    model = harness.config_module(c, "reference")
     data = data_mod.make(cfg, cfg["data"]["seed"])
     init = jax.jit(partial(model.init_params, model=cfg["model"]))
     for seed in args.seeds:

@@ -65,9 +65,8 @@ class Model:
     def __eq__(self, other):
         return isinstance(other, Model) and self._key() == other._key()
 
-    def loss(self, params, images, labels, precision):
-        return self.module.loss(params, images, labels, self.sizes,
-                                precision)
+    def loss(self, params, x, y, precision):
+        return self.module.loss(params, x, y, self.sizes, precision)
 
 
 class Reference:

@@ -24,10 +24,12 @@ splits the run key in four (next, arrivals, scheduler, gradient). Client
 ``i``'s uniform draw is ``uniform(fold_in(k_scheduler, i))``; the round's
 batch rows are ``randint(k_gradient, (n_cap, batch), 0, shard_size)``.
 
-The model enters as a module with ``loss(params, images, labels,
-precision)`` (the configuration's plain reference). ``dtype`` is the type
-the reference computes in: float32 at ``highest`` precision for the
-reference, bfloat16 at default precision for the control.
+The model enters as a module with ``loss(params, x, y, precision)`` (the
+configuration's plain reference). Shards are ``(n_cap, shard, ...)`` of
+any trailing shape. ``dtype`` is the type the reference computes in:
+float32 at ``highest`` precision for the reference, bfloat16 at default
+precision for the control; floating inputs are cast to it, integer ones
+(token ids) reach the model as they are.
 """
 
 from __future__ import annotations
@@ -109,6 +111,11 @@ def schedule(name: str, taus, n_cap: int, rounds: int, uniforms):
     return masks, scales
 
 
+def _floating_as(x, dtype):
+    """``x`` in ``dtype`` where it is floating; integers stay as they are."""
+    return x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x
+
+
 def data_weights(shard_sizes, n: int, n_cap: int):
     """(n_cap,) p_i = D_i / D over the ``n`` active clients, 0 beyond."""
     sizes = np.asarray(shard_sizes[:n], np.float64)
@@ -123,11 +130,11 @@ def _weighted_grad_block(params, shards_x, shards_y, rows, weights, k_grad,
     """sum_i w_i g_i over one block of client ``rows`` (padded rows carry
     weight 0), with the batch drawn for the whole population; ``keep``
     uses only the first ``keep`` examples of each client's batch."""
-    n_cap, shard = shards_y.shape
+    n_cap, shard = shards_y.shape[:2]
     idx = jax.random.randint(k_grad, (n_cap, batch), 0, shard)[rows, :keep]
     x = jax.vmap(lambda r, ix: shards_x[r][ix])(rows, idx)
     y = jax.vmap(lambda r, ix: shards_y[r][ix])(rows, idx)
-    x = x.astype(weights.dtype)
+    x = _floating_as(x, weights.dtype)
     grads = jax.vmap(jax.grad(
         lambda p, xb, yb: model.loss(p, xb, yb, precision)),
         in_axes=(None, 0, 0))(params, x, y)
@@ -138,7 +145,7 @@ def _weighted_grad_block(params, shards_x, shards_y, rows, weights, k_grad,
 
 @partial(jax.jit, static_argnames=("model", "precision"))
 def _held_out_loss(params, x, y, *, model, precision):
-    return model.loss(params, x.astype(jax.tree_util.tree_leaves(
+    return model.loss(params, _floating_as(x, jax.tree_util.tree_leaves(
         params)[0].dtype), y, precision).astype(jnp.float32)
 
 

@@ -3,10 +3,12 @@
 The cell's entry in ``BENCHMARK.json`` names a configuration and a
 traffic mix. Everything else is found by name:
 
-* ``bench/configs/<config>.json`` (the file ``BENCHMARK.json`` gives) —
-  sizes, data, limits of the comparison, and the names of two modules
-  beside it: ``reference`` (the plain model) and ``program`` (which of
-  the program's pieces run it);
+* the configuration's file (``BENCHMARK.json`` gives it) — sizes, limits
+  of the comparison, the name of its data set and the names of three
+  modules beside the file: ``reference`` (the plain model), ``program``
+  (which of the program's pieces run it) and ``counts`` (the FLOPs of
+  one example's training and forward pass);
+* ``bench/datasets/<dataset>.py`` — the data set (:mod:`harness.data`);
 * ``bench/traffic/<traffic>.json`` — parameters of the traffic; its
   ``kind`` picks the general driver (:mod:`harness.drivers`);
 * ``bench/metrics/<metric>.py`` — one reader per metric, ``read(run)``
@@ -46,9 +48,11 @@ def load_module(path: Path, name: str):
     return module
 
 
-def load_cell(workload: str, root: Path = ROOT) -> dict:
-    """The cell's BENCHMARK.json entries and the files they name."""
-    spec = json.loads((root / "BENCHMARK.json").read_text())
+def load_cell(workload: str, spec: dict | None = None) -> dict:
+    """The cell's entries in ``spec`` (by default BENCHMARK.json) and the
+    files they name, with paths from the checkout's root."""
+    if spec is None:
+        spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
     if workload not in cells:
         raise SystemExit(f"unknown workload {workload!r}; have "
@@ -61,11 +65,18 @@ def load_cell(workload: str, root: Path = ROOT) -> dict:
     per_layer = [m for m in spec["per_layer"]
                  if (workload in m["workloads"] if "workloads" in m
                      else m["moves"] in reported)]
-    return {"cell": cell,
-            "cfg": json.loads((root / entry["file"]).read_text()),
+    path = ROOT / entry["file"]
+    return {"cell": cell, "cfg": json.loads(path.read_text()),
+            "dir": path.parent,
             "traffic": json.loads(
                 (BENCH / "traffic" / f"{cell['traffic']}.json").read_text()),
             "end_to_end": end_to_end, "per_layer": per_layer}
+
+
+def config_module(c: dict, key: str):
+    """The module that a loaded cell's configuration names under ``key``,
+    beside its file."""
+    return load_module(c["dir"] / f"{c['cfg'][key]}.py", f"bench_{key}")
 
 
 class Run:
@@ -128,10 +139,8 @@ def main(argv=None, *, started: float | None = None) -> int:
     rng = np.random.default_rng(args.seed)
     weights_seed = int(rng.integers(0, 2**31))
     data = data_mod.make(cfg, cfg["data"]["seed"])
-    model = load_module(BENCH / "configs" / f"{cfg['reference']}.py",
-                        "bench_reference")
-    program = load_module(BENCH / "configs" / f"{cfg['program']}.py",
-                          "bench_program")
+    model = config_module(c, "reference")
+    program = config_module(c, "program")
     init = jax.jit(partial(model.init_params, model=cfg["model"]))
 
     def params0():
@@ -173,7 +182,8 @@ def main(argv=None, *, started: float | None = None) -> int:
     correct = correct and bool(answers)
 
     run = Run(facts=facts, record=record, cfg=cfg, traffic=traffic,
-              peaks=peaks, chips=len(chips), setup_s=setup_s,
+              counts=config_module(c, "counts"), peaks=peaks,
+              chips=len(chips), setup_s=setup_s,
               window_s=(trace_mod.window_s(record) if record
                         else facts["elapsed_s"]))
     result = {"correct": correct, "attempted": facts["attempted"],

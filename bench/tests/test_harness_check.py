@@ -14,15 +14,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from bench_tiny import BENCH, run_tiny, shrink
+from bench_tiny import run_tiny, shrink
 from harness import check, data as data_mod, main
 
 
 def _reference(seed):
     c = shrink(main.load_cell("fig1_cnn.grid"))
     cfg = c["cfg"]
-    model = main.load_module(BENCH / "configs" / f"{cfg['reference']}.py",
-                             "ref_check")
+    model = main.config_module(c, "reference")
     init = jax.jit(partial(model.init_params, model=cfg["model"]))
     return cfg, check.Reference(cfg, model, data_mod.make(cfg, seed),
                                 lambda: init(jax.random.PRNGKey(seed)))

@@ -1,6 +1,5 @@
 """The readers of the program's host spans and counters, on a small
-recorded trace and fake program records; and the fused update kernel's
-name as the chip's trace shows it."""
+recorded trace and fake program records."""
 
 import sys
 
@@ -135,35 +134,3 @@ def test_span_readers_are_none_without_the_program_records(run,
     monkeypatch.setitem(sys.modules, "repro.tracing", None)
     for name in ["idle_share.engine", "window_compiles"] + HOST_MS:
         assert reader(name).read(run) is None, name
-
-
-# The fused update kernel's event in a v5e trace of fig1_cnn.grid: the
-# pallas_call's name is the HLO instruction's.
-KERNEL_EVENT = ("%masked_scaled_aggregate_update.12 = f32[8,1,1069056]"
-                "{2,1,0:T(1,128)S(1)} custom-call(f32[1,1]{1,0:T(1,128)} "
-                "%get-tuple-element.980, f32[8,1,40]{2,1,0:T(1,128)S(1)} "
-                "%bitcast.694)")
-
-
-def test_update_kernel_roofline_finds_the_named_kernel():
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels.aggregate.aggregate import (
-        masked_scaled_aggregate_update_kernel,
-    )
-
-    roofline = reader("update_kernel_roofline")
-    g, w = jnp.ones((4, 256)), jnp.ones((4,))
-    text = str(jax.make_jaxpr(lambda: masked_scaled_aggregate_update_kernel(
-        g, w, 0.1, jnp.ones((256,)), interpret=True))())
-    assert f"name={roofline.KERNEL}" in text  # the program names it so
-    name = KERNEL_EVENT.split(" = ")[0].rsplit(".", 1)[0]
-    rec = {"devices": {"0": [[name, 100, 1000, KERNEL_EVENT],
-                             ["%fusion", 1100, 500, "%fusion.3 = f32[8]"]]},
-           "host": [["window", 0, 2000]]}
-    cfg = {"model": {"n_params": 1000}, "n_clients": 8}
-    peaks = {"hbm_bytes_per_s": 1e12}
-    run = Run(record=rec, facts={"updates": 10}, cfg=cfg, peaks=peaks)
-    # 10 updates of 4 * 1000 * (8 + 2) bytes at 1e12 B/s over 1000 ns
-    assert roofline.read(run) == pytest.approx(100 * 4e5 / 1e12 / 1e-6)
